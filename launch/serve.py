@@ -27,6 +27,7 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 
 from repro.core.database import IPDB
 from repro.frontdoor import FrontDoor, FrontDoorClient, QueryRejected
+from repro.launch.compile_cache import setup_compile_cache
 from repro.relational.table import Table
 
 
@@ -145,6 +146,7 @@ def main(argv=None) -> int:
     ap.add_argument("--hold", action="store_true",
                     help="keep the front door up after the demo drive")
     args = ap.parse_args(argv)
+    setup_compile_cache()
     if args.frontdoor:
         return run_frontdoor(args)
     return run_inprocess(args.rows)

@@ -8,7 +8,9 @@
 Executor resolution by model PATH scheme:
     oracle:<name>   → OracleExecutor using a registered oracle fn
     jax:<arch>      → JaxExecutor on an in-process InferenceEngine
-                      (smoke-size config of the named architecture)
+                      (smoke-size config of the named architecture;
+                      OPTIONS {'smoke': 0} serves its published widths,
+                      'seed' picks the random weights)
     *.onnx / tabular:<name> → TabularExecutor via a registered predict fn
     custom:<name>   → a registered executor factory (tests/benchmarks)
 """
@@ -101,7 +103,8 @@ class IPDB:
             self.options.update(session_options)
         self._oracles: Dict[str, Callable] = {}
         self._tabular_fns: Dict[str, Callable] = {}
-        # keyed (arch, kv_layout, page_size, pool_pages)
+        # keyed (arch, kv_layout, page_size, pool_pages, max_len,
+        # prefix_mode, kv_quant, smoke, seed)
         self._jax_engines: Dict[tuple, object] = {}
         self._oracle_kwargs: Dict[str, dict] = {}
         self._executor_factories: Dict[str, Callable] = {}
@@ -247,15 +250,23 @@ class IPDB:
                 "kv_quant", self.options.get("kv_quant", "none")))
             if layout == "dense":
                 pmode, quant = "radix", "none"
+            # 'smoke': 0 serves the published configuration at its own
+            # widths and vocabulary (byte-token ids are a subset of it);
+            # the default is the arch's tiny smoke variant over the
+            # 259-id byte vocabulary
+            smoke = bool(int(entry.options.get("smoke", 1)))
+            seed = int(entry.options.get("seed", 0))      # random weights
             # every option that shapes the engine is part of the cache
             # key — two models must never silently share a mismatched one
-            key = (arch, layout, page_size, pool, max_len, pmode, quant)
+            key = (arch, layout, page_size, pool, max_len, pmode, quant,
+                   smoke, seed)
             if key not in self._jax_engines:
                 import repro.configs as C
                 from repro.serving.engine import InferenceEngine
-                cfg = C.get_smoke_config(arch).replace(vocab_size=259)
+                cfg = C.get_smoke_config(arch).replace(vocab_size=259) \
+                    if smoke else C.get_config(arch)
                 self._jax_engines[key] = InferenceEngine(
-                    cfg, max_len=max_len,
+                    cfg, seed=seed, max_len=max_len,
                     kv_layout=layout, page_size=page_size,
                     page_pool_pages=pool, prefix_cache_mode=pmode,
                     kv_quant=quant)

@@ -37,15 +37,15 @@ def _kernel(qpos_ref, spos_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qpos = qpos_ref[0]                                   # (1,) this sequence
-    spos = spos_ref[0]                                   # (bl,) slot positions
+    qpos = qpos_ref[0]                                   # (1, 1) this sequence
+    spos = spos_ref[0]                                   # (1, bl) slot positions
 
     q = q_ref[0].astype(jnp.float32) * scale             # (G, D)
     k = k_ref[0].astype(jnp.float32)                     # (bl, D)
     v = v_ref[0].astype(jnp.float32)                     # (bl, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # (G, bl)
-    ok = (spos >= 0) & (spos <= qpos[0])
-    s = jnp.where(ok[None, :], s, NEG_INF)
+    ok = (spos >= 0) & (spos <= qpos)                    # (1, bl)
+    s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -65,7 +65,10 @@ def _kernel(qpos_ref, spos_ref, q_ref, k_ref, v_ref,
 def decode_attention_pallas(q, k_cache, v_cache, slot_positions, q_position,
                             *, block_l: int = 512, interpret: bool = False):
     """q (BK, G, D); k_cache, v_cache (BK, L, D); slot_positions (BK, L);
-    q_position (BK, 1). Returns (BK, G, D)."""
+    q_position (BK, 1). Returns (BK, G, D).
+
+    The position arrays ride as (BK, 1, X) so each block's last two dims
+    are (1, X) with 1 the full middle dim — the TPU tiling rule."""
     BK, G, D = q.shape
     L = k_cache.shape[1]
     assert L % block_l == 0, (L, block_l)
@@ -77,8 +80,8 @@ def decode_attention_pallas(q, k_cache, v_cache, slot_positions, q_position,
         kern,
         grid=(BK, nl),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),             # q_position
-            pl.BlockSpec((1, block_l), lambda b, j: (b, j)),       # slot pos
+            pl.BlockSpec((1, 1, 1), lambda b, j: (b, 0, 0)),       # q_position
+            pl.BlockSpec((1, 1, block_l), lambda b, j: (b, 0, j)),  # slot pos
             pl.BlockSpec((1, G, D), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, block_l, D), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, block_l, D), lambda b, j: (b, j, 0)),
@@ -91,7 +94,8 @@ def decode_attention_pallas(q, k_cache, v_cache, slot_positions, q_position,
             pltpu.VMEM((G, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q_position, slot_positions, q, k_cache, v_cache)
+    )(q_position.reshape(BK, 1, 1), slot_positions.reshape(BK, 1, L),
+      q, k_cache, v_cache)
     return out
 
 
@@ -205,12 +209,12 @@ def _paged_quant_kernel(bt_ref, nact_ref, qpos_ref, q_ref, k_ref, v_ref,
         # frozen pages read the int8 shadow × per-page scale; live pages
         # read the fp pool — both blocks arrive via the same table-chased
         # index_map, the select is pure VPU work
-        frozen = fl_ref[0, 0] > 0
+        frozen = fl_ref[0] > 0                               # (1, 1)
         k = jnp.where(frozen,
-                      kq_ref[0].astype(jnp.float32) * ks_ref[0, 0],
+                      kq_ref[0].astype(jnp.float32) * ks_ref[0],
                       k_ref[0].astype(jnp.float32))          # (ps, D)
         v = jnp.where(frozen,
-                      vq_ref[0].astype(jnp.float32) * vs_ref[0, 0],
+                      vq_ref[0].astype(jnp.float32) * vs_ref[0],
                       v_ref[0].astype(jnp.float32))
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # (G, ps)
         tok = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
@@ -240,7 +244,9 @@ def decode_attention_paged_quant_pallas(q, k_pool, v_pool, kq_pool, vq_pool,
     (P, ps, D) int8 shadow pools; kscale/vscale (P, 1) float32 per-page
     scales; quant_flags (P, 1) int32 (>0 ⇒ page is frozen/quantized).
     Remaining arguments and the streamed-softmax structure are identical —
-    the only delta is a per-page dequant select on the fetched block."""
+    the only delta is a per-page dequant select on the fetched block.
+    Scales and flags ride as (P, 1, 1) so their (1, 1) blocks span the
+    full last two dims, as the TPU tiling rule requires."""
     BK, G, D = q.shape
     P, ps, _ = k_pool.shape
     NB = block_tables.shape[1]
@@ -257,9 +263,9 @@ def decode_attention_paged_quant_pallas(q, k_pool, v_pool, kq_pool, vq_pool,
             pl.BlockSpec((1, ps, D), lambda b, j, bt, na, qp: (bt[b, j], 0, 0)),
             pl.BlockSpec((1, ps, D), lambda b, j, bt, na, qp: (bt[b, j], 0, 0)),
             pl.BlockSpec((1, ps, D), lambda b, j, bt, na, qp: (bt[b, j], 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j, bt, na, qp: (bt[b, j], 0)),
-            pl.BlockSpec((1, 1), lambda b, j, bt, na, qp: (bt[b, j], 0)),
-            pl.BlockSpec((1, 1), lambda b, j, bt, na, qp: (bt[b, j], 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j, bt, na, qp: (bt[b, j], 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j, bt, na, qp: (bt[b, j], 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j, bt, na, qp: (bt[b, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G, D), lambda b, j, bt, na, qp: (b, 0, 0)),
         scratch_shapes=[
@@ -274,5 +280,6 @@ def decode_attention_paged_quant_pallas(q, k_pool, v_pool, kq_pool, vq_pool,
         out_shape=jax.ShapeDtypeStruct((BK, G, D), q.dtype),
         interpret=interpret,
     )(block_tables, num_active, q_position, q, k_pool, v_pool,
-      kq_pool, vq_pool, kscale, vscale, quant_flags)
+      kq_pool, vq_pool, kscale.reshape(P, 1, 1), vscale.reshape(P, 1, 1),
+      quant_flags.reshape(P, 1, 1))
     return out

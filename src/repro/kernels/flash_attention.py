@@ -37,8 +37,8 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref,   # inputs
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qpos = qpos_ref[0]                                  # (bq,)
-    kpos = kpos_ref[0]                                  # (bk,)
+    qpos = qpos_ref[0]                                  # (bq, 1) column
+    kpos = kpos_ref[0]                                  # (1, bk) row
 
     # block-level skip: whole block beyond causal frontier / outside window
     qmax = jnp.max(qpos)
@@ -60,13 +60,13 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref,   # inputs
         v = v_ref[0].astype(jnp.float32)                # (bk, D)
         s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())))  # (G,bq,bk)
 
-        valid = kpos[None, :] >= 0
+        valid = kpos >= 0                                # (1, bk)
         if causal:
-            ok = valid & (kpos[None, :] <= qpos[:, None])
+            ok = valid & (kpos <= qpos)                  # (bq, bk)
             if window > 0:
-                ok &= kpos[None, :] > qpos[:, None] - window
+                ok &= kpos > qpos - window
             if prefix_len > 0:
-                ok |= valid & (kpos[None, :] < prefix_len)
+                ok |= valid & (kpos < prefix_len)
         else:
             ok = valid
         s = jnp.where(ok[None], s, NEG_INF)
@@ -92,7 +92,11 @@ def flash_attention_pallas(q, k, v, q_positions, kv_positions, *,
                            block_kv: int = 512, interpret: bool = False):
     """q (BK, G, Sq, D); k, v (BK, Skv, D); q_positions (BK, Sq);
     kv_positions (BK, Skv). BK = batch × kv_heads (folded in ops.py).
-    Returns (BK, G, Sq, D)."""
+    Returns (BK, G, Sq, D).
+
+    Query positions ride as a (BK, Sq, 1) column and kv positions as a
+    (BK, 1, Skv) row, so both blocks meet the TPU tiling rule and the mask
+    broadcasts without a transpose."""
     BK, G, Sq, D = q.shape
     Skv = k.shape[1]
     assert Sq % block_q == 0 and Skv % block_kv == 0, (Sq, Skv)
@@ -106,8 +110,8 @@ def flash_attention_pallas(q, k, v, q_positions, kv_positions, *,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),        # qpos
-            pl.BlockSpec((1, block_kv), lambda b, i, j: (b, j)),       # kpos
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),  # qpos
+            pl.BlockSpec((1, 1, block_kv), lambda b, i, j: (b, 0, j)),  # kpos
             pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0)),
             pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
@@ -120,5 +124,6 @@ def flash_attention_pallas(q, k, v, q_positions, kv_positions, *,
             pltpu.VMEM((G, block_q, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q_positions, kv_positions, q, k, v)
+    )(q_positions.reshape(BK, Sq, 1), kv_positions.reshape(BK, 1, Skv),
+      q, k, v)
     return out

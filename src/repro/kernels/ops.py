@@ -2,7 +2,7 @@
 
 These own the layout plumbing (GQA head folding, D-padding to 128, expert
 sort + group padding) so model code can call them with natural shapes. On
-this CPU container they run with interpret=True; on TPU, interpret=False
+the CPU they run with interpret=True; on TPU, interpret=False
 compiles the real Mosaic kernels. `use_interpret()` resolves the default
 from the backend.
 """

@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
-# This flag is set ONLY here (never in conftest/pyproject): smoke tests and
-# benches see the real single CPU device.
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell and
 record memory_analysis / cost_analysis / collective bytes for §Dry-run and
 §Roofline.
@@ -31,6 +25,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import subprocess
 import sys
@@ -379,4 +374,10 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # Set only when run as a program, before jax is first imported (every
+    # jax import above is inside a function): 512 virtual CPU devices for
+    # the meshes, and the process pinned to the CPU.  Importing this module
+    # leaves the caller's XLA_FLAGS and platform alone.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     main()

@@ -1,13 +1,12 @@
 """Serving driver: bring up the engine + continuous batcher and run a
 request stream (the deployable analog of examples/serve_e2e.py).
 
-    PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b \
         --requests 16 --slots 4 [--schema 'topic VARCHAR,score INTEGER']
 
-On TPU hardware the same builders (launch.steps.make_prefill_step /
-make_decode_step with the `resident` layout — see EXPERIMENTS.md §Perf)
-drive the full-size configs; on this CPU host the smoke configs exercise
-the identical code path.
+``--smoke`` (the default) serves the arch's tiny smoke configuration over
+the byte vocabulary, which runs on any backend; ``--no-smoke`` serves the
+published configuration, which needs a TPU.
 """
 from __future__ import annotations
 
@@ -16,7 +15,10 @@ import json
 import sys
 import time
 
+import jax
+
 import repro.configs as C
+from repro.launch.compile_cache import setup_compile_cache
 from repro.serving.engine import InferenceEngine
 from repro.serving.grammar import Field, JsonGrammar
 from repro.serving.scheduler import ContinuousBatcher, Request
@@ -33,7 +35,8 @@ def parse_schema(s: str):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new-tokens", type=int, default=64)
@@ -41,6 +44,11 @@ def main(argv=None) -> int:
     ap.add_argument("--schema", default="label VARCHAR")
     ap.add_argument("--max-len", type=int, default=512)
     args = ap.parse_args(argv)
+    if not args.smoke and jax.default_backend() != "tpu":
+        print(f"[serve] published widths need a TPU; backend is "
+              f"{jax.default_backend()}", file=sys.stderr)
+        return 2
+    setup_compile_cache()
 
     cfg = C.get_smoke_config(args.arch).replace(vocab_size=259) \
         if args.smoke else C.get_config(args.arch)

@@ -145,9 +145,9 @@ class _PrefixEntry:
 
 
 class InferenceEngine:
-    """Single-host engine around one model. Tiny configs run the real JAX
-    forward on CPU; the same code drives full configs on a TPU mesh (the
-    steps come from launch.steps builders in that path)."""
+    """Single-host engine around one model. Smoke configs run the real JAX
+    forward on the CPU; the same code serves published configs on one TPU
+    chip (see chip_smoke.py)."""
 
     def __init__(self, cfg: ModelConfig, params=None, *,
                  seed: int = 0, max_len: int = 1024,
@@ -207,14 +207,14 @@ class InferenceEngine:
         if key not in self._prefill_cache:
             cfg = self.cfg
 
-            def fn(params, tokens, positions, cache):
+            def prefill_step(params, tokens, positions, cache):
                 logits, cache = MDL.forward(
                     cfg, params, {"tokens": tokens, "positions": positions},
                     mode="prefill", cache=cache, remat=False,
                     extend_offset=offset, last_only=True)
                 return logits[:, -1], cache
 
-            self._prefill_cache[key] = jax.jit(fn)
+            self._prefill_cache[key] = jax.jit(prefill_step)
         return self._prefill_cache[key]
 
     def _decode_fn(self):
@@ -225,14 +225,14 @@ class InferenceEngine:
                 from repro.kernels import ops as KOPS
                 datt = KOPS.decode_attention
 
-            def fn(params, tokens, positions, cache):
+            def decode_step(params, tokens, positions, cache):
                 logits, cache = MDL.forward(
                     cfg, params, {"tokens": tokens, "positions": positions},
                     mode="decode", cache=cache, remat=False,
                     decode_attn_fn=datt)
                 return logits[:, 0], cache
 
-            self._decode_fns["fn"] = jax.jit(fn, donate_argnums=(3,))
+            self._decode_fns["fn"] = jax.jit(decode_step, donate_argnums=(3,))
         return self._decode_fns["fn"]
 
     def _decode_fn_paged(self, num_blocks: int):
@@ -247,7 +247,7 @@ class InferenceEngine:
                 from repro.kernels import ops as KOPS
                 datt = KOPS.decode_attention_paged
 
-            def fn(params, tokens, positions, cache, bt, qf):
+            def paged_decode_step(params, tokens, positions, cache, bt, qf):
                 cache = dict(cache, block_tables=bt)
                 if qf is not None:
                     cache["quant_flags"] = qf
@@ -257,7 +257,8 @@ class InferenceEngine:
                     decode_attn_fn=datt)
                 return logits[:, 0], cache
 
-            self._decode_fns[key] = jax.jit(fn, donate_argnums=(3,))
+            self._decode_fns[key] = jax.jit(paged_decode_step,
+                                            donate_argnums=(3,))
         return self._decode_fns[key]
 
     # ------------------------------- prefill ----------------------------------
@@ -672,7 +673,8 @@ class InferenceEngine:
             # block table / prefix table / quant flags ride OUTSIDE the
             # donated cache: they are rebuilt host-side every call,
             # donation buys nothing
-            def fn(params, tokens, positions, cache, bt, ptab, plen, qf):
+            def paged_prefill_step(params, tokens, positions, cache, bt,
+                                   ptab, plen, qf):
                 cache = dict(cache, block_tables=bt, prefix_table=ptab,
                              prefix_len=plen)
                 if qf is not None:
@@ -682,7 +684,8 @@ class InferenceEngine:
                     mode="prefill", cache=cache, remat=False, last_only=True)
                 return logits[:, -1], cache
 
-            self._prefill_cache[key] = jax.jit(fn, donate_argnums=(3,))
+            self._prefill_cache[key] = jax.jit(paged_prefill_step,
+                                               donate_argnums=(3,))
         qf = None if self._quant_flags is None \
             else jnp.asarray(self._quant_flags)
         logits, out = self._prefill_cache[key](
@@ -956,7 +959,7 @@ class InferenceEngine:
                 jnp.asarray(logits), jnp.asarray(mask),
                 None if noise is None else jnp.asarray(noise),
                 temperature=max(temperature, 1e-6) if temperature > 0 else 1.0,
-                block_v=256, interpret=True))
+                block_v=256))
         x = logits / (temperature if temperature > 0 else 1.0)
         if noise is not None:
             x = x + noise

@@ -139,6 +139,31 @@ def test_real_jax_executor_end_to_end():
     assert r.stats.llm_calls == 2          # ceil(3 unique / batch 2)
 
 
+def test_jax_model_options_pick_the_published_config(monkeypatch):
+    """OPTIONS {'smoke': 0} serves the arch's published widths over its
+    full vocabulary; smoke and seed are part of the engine cache key."""
+    import repro.configs as C
+    import repro.serving.engine as E
+    built = []
+
+    class Recorder:
+        def __init__(self, cfg, **kw):
+            built.append((cfg, kw))
+
+    monkeypatch.setattr(E, "InferenceEngine", Recorder)
+    d = IPDB()
+    d.sql("CREATE LLM MODEL big PATH 'jax:olmo-1b' ON PROMPT "
+          "OPTIONS { 'smoke': 0, 'seed': 3 }")
+    d.sql("CREATE LLM MODEL tiny PATH 'jax:olmo-1b' ON PROMPT")
+    for name in ("big", "tiny", "big"):
+        d._make_executor(d.catalog.model(name))
+    assert len(built) == 2                 # the second "big" reuses its engine
+    (big, kw_big), (tiny, kw_tiny) = built
+    assert big == C.get_config("olmo-1b") and big.vocab_size == 50304
+    assert (kw_big["seed"], kw_tiny["seed"]) == (3, 0)
+    assert tiny == C.get_smoke_config("olmo-1b").replace(vocab_size=259)
+
+
 def test_tabular_model_full_path():
     """CREATE TABULAR MODEL → TabularExecutor (hubert-style classifier)."""
     d = IPDB()
