@@ -32,6 +32,7 @@ from repro.core.rewrite import rewrites_section
 from repro.core.service import InferenceService
 from repro.core.stats import (CostModel, PilotSampler, StatisticsStore,
                               stats_section)
+from repro.core.trace import span
 from repro.relational.binder import Binder
 from repro.relational.catalog import Catalog, ModelEntry
 from repro.relational.executor import ExecStats, PlanExecutor
@@ -357,7 +358,8 @@ class IPDB:
 
     # -- entry point -------------------------------------------------------
     def sql(self, query: str, *, explain: bool = False) -> QueryResult:
-        stmt = parse_sql(query)
+        with span("sql.parse"):
+            stmt = parse_sql(query)
         if isinstance(stmt, SetStmt):
             self.options[stmt.key] = stmt.value
             return QueryResult(None, ExecStats())
@@ -393,16 +395,20 @@ class IPDB:
         still-queued requests within one flush.  Only SELECT statements
         stream; DDL/SET go through `sql()`."""
         t0 = time.time()
-        stmt = parse_sql(query)
+        with span("sql.parse"):
+            stmt = parse_sql(query)
         if not isinstance(stmt, SelectStmt):
             raise ValueError("stream() supports SELECT statements only; "
                              f"got {type(stmt).__name__}")
         scope = cancel_scope if cancel_scope is not None else CancelScope()
         svc = self.inference_service
-        with self._bind_lock:
+        with span("await_plan_lock"):
+            self._bind_lock.acquire()
+        try:
             self._stream_seq += 1
             tag = session or f"q{self._stream_seq}"
-            plan = Binder(self.catalog, self.options).bind_select(stmt)
+            with span("sql.bind"):
+                plan = Binder(self.catalog, self.options).bind_select(stmt)
             svc.max_dispatch = int(self.options.get("max_dispatch_calls", 0))
             svc.speculative = bool(self.options.get("speculative_flush",
                                                     True))
@@ -411,7 +417,10 @@ class IPDB:
             pilot = self._make_pilot()
             opt = Optimizer(self.catalog, self.options,
                             stats=self.stats_store, pilot=pilot)
-            plan = opt.optimize(plan)
+            with span("sql.optimize"):
+                plan = opt.optimize(plan)
+        finally:
+            self._bind_lock.release()
         # deadline anchoring: operators derive their own deadline_ts from
         # the precedence-resolved deadline_ms (session < OPTIONS < WITH)
         # against this shared monotonic query start, so every expression
@@ -528,7 +537,8 @@ class IPDB:
 
     def _run_select(self, stmt: SelectStmt, explain: bool) -> QueryResult:
         t0 = time.time()
-        plan = Binder(self.catalog, self.options).bind_select(stmt)
+        with span("sql.bind"):
+            plan = Binder(self.catalog, self.options).bind_select(stmt)
         svc = self.inference_service
         # apply the dispatch configuration BEFORE optimizing: pilot
         # sampling inside optimize() dispatches through the service too
@@ -541,7 +551,8 @@ class IPDB:
         pilot = self._make_pilot()
         opt = Optimizer(self.catalog, self.options, stats=self.stats_store,
                         pilot=pilot)
-        plan = opt.optimize(plan)
+        with span("sql.optimize"):
+            plan = opt.optimize(plan)
         # one monotonic anchor per query: deadline_ms (from any precedence
         # level) counts down from here in every operator
         extra: Dict[str, object] = {"query_start_ts": time.monotonic()}
@@ -565,6 +576,7 @@ class IPDB:
         st = ex.stats
         st.dispatch_batches = svc.stats.dispatch_batches \
             - before.dispatch_batches
+        st.queue_wait_s = svc.stats.queue_wait_s - before.queue_wait_s
         calls = svc.stats.dispatched_calls - before.dispatched_calls
         st.mean_batch_occupancy = (calls / st.dispatch_batches
                                    if st.dispatch_batches else 0.0)
@@ -662,6 +674,7 @@ class QueryStream:
         sess = svc.session_stats(self.session)
         if sess is not None:
             st.dispatch_batches = sess.dispatch_batches
+            st.queue_wait_s = sess.queue_wait_s
             st.mean_batch_occupancy = (
                 sess.dispatched_calls / sess.dispatch_batches
                 if sess.dispatch_batches else 0.0)

@@ -43,6 +43,9 @@ class CallResult:
     decode_tokens: int = 0        # lock-step decode tokens generated
     prefix_hits: int = 0          # shared-prefix KV memo/radix hits
     radix_hit_tokens: int = 0     # prompt tokens served from the radix tree
+    decode_steps: int = 0         # decode ticks the engine ran
+    decode_rows: int = 0          # live rows summed over those ticks
+    decode_slots: int = 0         # decode-batch width summed over them
     # per-answer confidence scores, one per returned row, aligned with the
     # parsed objects.  Backends with calibrated scores (tabular classifiers,
     # oracles carrying a "__confidence__" field) populate them; text-only
@@ -154,7 +157,10 @@ class JaxExecutor(Predictor):
                           wall, wall, prefill_tokens=s.prefill_tokens,
                           decode_tokens=s.output_tokens,
                           prefix_hits=s.prefix_hits,
-                          radix_hit_tokens=s.radix_hit_tokens)
+                          radix_hit_tokens=s.radix_hit_tokens,
+                          decode_steps=s.decode_steps,
+                          decode_rows=s.decode_rows,
+                          decode_slots=s.decode_slots)
 
     def complete_many(self, prompts, schema, num_rows_list, *,
                       shared_prefix="", rows_list=None, instruction=""):
@@ -213,8 +219,7 @@ class JaxExecutor(Predictor):
                         max_new_tokens=max_new, n_samples=ns)
                 for p, nr in zip(run_prompts, num_rows_list)]
         bs = self._batcher.stats
-        before = (bs.prefill_tokens, bs.output_tokens, bs.prefix_hits,
-                  bs.radix_hit_tokens)
+        before = dataclasses.replace(bs)
         t0 = time.time()
         done = self._batcher.run(
             reqs, temperature=float(self.options.get("temperature", 0.7)),
@@ -229,10 +234,13 @@ class JaxExecutor(Predictor):
         # whole-run engine accounting rides on the first result (per-row
         # attribution of lock-step prefill/decode work is arbitrary; the
         # operator only ever sums these)
-        out[0].prefill_tokens = bs.prefill_tokens - before[0]
-        out[0].decode_tokens = bs.output_tokens - before[1]
-        out[0].prefix_hits = bs.prefix_hits - before[2]
-        out[0].radix_hit_tokens = bs.radix_hit_tokens - before[3]
+        out[0].prefill_tokens = bs.prefill_tokens - before.prefill_tokens
+        out[0].decode_tokens = bs.output_tokens - before.output_tokens
+        out[0].prefix_hits = bs.prefix_hits - before.prefix_hits
+        out[0].radix_hit_tokens = bs.radix_hit_tokens - before.radix_hit_tokens
+        out[0].decode_steps = bs.decode_steps - before.decode_steps
+        out[0].decode_rows = bs.decode_rows - before.decode_rows
+        out[0].decode_slots = bs.decode_slots - before.decode_slots
         return out
 
 

@@ -42,6 +42,7 @@ from repro.core.faults import DeadlineExceeded, TransientError
 from repro.core.service import (DispatchGroup, InferenceHandle,
                                 InferenceRequest, InferenceService, makespan)
 from repro.core.stats import stats_key
+from repro.core.trace import span
 from repro.relational.plan import PredictInfo
 from repro.relational.table import Table, _coerce
 
@@ -86,7 +87,6 @@ class PredictStats:
     out_tokens: int = 0
     sim_latency_s: float = 0.0     # modeled makespan (workers + rate limit)
     serial_latency_s: float = 0.0  # sum of per-call latencies
-    wall_s: float = 0.0
     rows_in: int = 0
     cache_hits: int = 0
     retries: int = 0
@@ -100,6 +100,9 @@ class PredictStats:
     decode_tokens: int = 0         # lock-step decode tokens generated
     prefix_hits: int = 0           # shared-prefix KV memo/radix hits
     radix_hit_tokens: int = 0      # prompt tokens served from the radix tree
+    decode_steps: int = 0          # decode ticks the engine ran
+    decode_rows: int = 0           # live rows summed over those ticks
+    decode_slots: int = 0          # decode-batch width summed over them
     # cascade accounting (CascadePredictor backend; zero for direct routes)
     proxy_calls: int = 0           # proxy-stage prompts scored
     escalated_calls: int = 0       # expensive-stage calls actually made
@@ -466,7 +469,10 @@ class PredictOperator:
         """Phase 1: probe caches, marshal the misses into batched requests
         and queue them on the inference service.  Returns without
         dispatching — `resolve` (or any service flush) does that."""
-        t0 = time.time()
+        with span("predict.marshal"):
+            return self._submit(table)
+
+    def _submit(self, table: Table) -> PendingChunk:
         n = len(table)
         self.stats.rows_in += n
         in_cols = [c for c in self.info.inputs]
@@ -511,8 +517,6 @@ class PredictOperator:
             handle, owned = self._submit_call(prompt, len(batch_rows),
                                               batch_rows, instr)
             batches.append(PendingBatch(idxs, batch_rows, handle, owned))
-
-        self.stats.wall_s += time.time() - t0
         return PendingChunk(table, keys, use_dedup, seen, cached, batches,
                             group)
 
@@ -534,8 +538,11 @@ class PredictOperator:
         still cancel them undispatched.  The per-handle `result()` calls
         below then block on any lane futures (synchronous backends
         dispatch inline during the drain)."""
-        t0 = time.time()
         self.service.drain_for([b.handle for b in pending.batches])
+        with span("predict.extract"):
+            return self._extract(pending)
+
+    def _extract(self, pending: PendingChunk) -> Table:
         results: Dict[int, List[Optional[object]]] = {}
         for b in pending.batches:
             vals = self._resolve_batch(b, pending.group)
@@ -564,7 +571,6 @@ class PredictOperator:
             colvals = [v[j] for v in out_vals]
             self.stats.null_outputs += sum(1 for v in colvals if v is None)
             out = out.with_column(col, _coerce(colvals, typ), typ)
-        self.stats.wall_s += time.time() - t0
         return out
 
     def cancel(self, pending: PendingChunk) -> None:
@@ -577,7 +583,6 @@ class PredictOperator:
 
     # table generation (ρ^s)
     def scan(self, max_rows: int = 64) -> Table:
-        t0 = time.time()
         group = self._open_group()
         prompt = self._instruction() + \
             f"\nReturn a JSON array of at most {max_rows} objects."
@@ -603,13 +608,11 @@ class PredictOperator:
         for (n, t), c in zip(self.info.outputs, self.info.out_cols):
             cols[c] = _coerce([r.get(n) for r in rows], t)
             sch[c] = t
-        self.stats.wall_s += time.time() - t0
         return Table(cols, sch)
 
     # semantic aggregate (LLM AGG): one call per group, all groups
     # dispatched as one service batch
     def aggregate(self, groups: List[List[dict]]) -> List[Optional[object]]:
-        t0 = time.time()
         group = self._open_group()
         instr = self._instruction()
         suffix = "\nAggregate ALL rows into ONE JSON object."
@@ -645,7 +648,6 @@ class PredictOperator:
             outs.append(parsed[0][self.info.outputs[0][0]] if parsed else None)
         self.stats.sim_latency_s += group.makespan()
         self.stats.serial_latency_s += group.serial()
-        self.stats.wall_s += time.time() - t0
         return outs
 
     # ------------------------------------------------------------------
@@ -704,6 +706,9 @@ class PredictOperator:
         self.stats.decode_tokens += res.decode_tokens
         self.stats.prefix_hits += res.prefix_hits
         self.stats.radix_hit_tokens += res.radix_hit_tokens
+        self.stats.decode_steps += res.decode_steps
+        self.stats.decode_rows += res.decode_rows
+        self.stats.decode_slots += res.decode_slots
         self.stats.proxy_calls += res.proxy_calls
         self.stats.escalated_calls += res.escalated_calls
         self.stats.cascade_rows += res.cascade_rows

@@ -69,6 +69,7 @@ from repro.core.executors import CallResult, Predictor, default_latency_model
 from repro.core.faults import (CLOSED, BackendTimeout, CircuitBreaker,
                                CircuitOpenError, DeadlineExceeded,
                                TransientError)
+from repro.core.trace import span
 
 
 def makespan(latencies: Sequence[float], workers: int, rpm: float = 0.0
@@ -187,7 +188,7 @@ class InferenceHandle:
     without dispatch (cancel, shutdown, a failed flush) stays result-less
     and `result()` raises."""
     __slots__ = ("request", "_service", "_result", "_error", "_event",
-                 "refs")
+                 "refs", "submitted_s")
 
     def __init__(self, request: InferenceRequest, service: "InferenceService"):
         self.request = request
@@ -196,6 +197,7 @@ class InferenceHandle:
         self._error: Optional[BaseException] = None
         self._event: Optional[threading.Event] = None
         self.refs = 1                  # submitters sharing this handle
+        self.submitted_s = time.perf_counter()
 
     @property
     def done(self) -> bool:
@@ -228,6 +230,7 @@ class SessionCounters:
     deadline_drops: int = 0            # requests dropped past their deadline
     backend_timeouts: int = 0          # dispatch batches killed by call timeout
     breaker_rejections: int = 0        # requests shed by an open breaker
+    queue_wait_s: float = 0.0          # see ServiceStats.queue_wait_s
 
 
 @dataclasses.dataclass
@@ -247,6 +250,9 @@ class ServiceStats:
     backend_timeouts: int = 0          # dispatch batches killed by call timeout
     breaker_rejections: int = 0        # requests shed by an open breaker
     degraded_calls: int = 0            # cascade batches degraded to proxy-only
+    # summed over dispatch batches: from the submit of a batch's first
+    # request to the start of its executor call (real time)
+    queue_wait_s: float = 0.0
 
     @property
     def mean_batch_occupancy(self) -> float:
@@ -772,8 +778,11 @@ class InferenceService:
             self._fail_batch(handles, CircuitOpenError(
                 f"circuit open for backend {reqs[0].model_name!r}"))
             return
+        wait = time.perf_counter() - handles[0].submitted_s
         try:
-            results = self._call_executor(executor, reqs)
+            with span("service.dispatch", requests=len(reqs),
+                      session=reqs[0].session):
+                results = self._call_executor(executor, reqs)
         except BaseException as e:
             if isinstance(e, BackendTimeout):
                 with self._lock:
@@ -796,6 +805,7 @@ class InferenceService:
         with self._lock:
             self.stats.dispatch_batches += 1
             self.stats.dispatched_calls += len(reqs)
+            self.stats.queue_wait_s += wait
             if background:
                 self.stats.async_batches += 1
             # batches are session/tenant-homogeneous (tags are part of
@@ -804,6 +814,7 @@ class InferenceService:
             if sess is not None:
                 sess.dispatch_batches += 1
                 sess.dispatched_calls += len(reqs)
+                sess.queue_wait_s += wait
             if reqs[0].tenant:
                 self._tenant_calls[reqs[0].tenant] += len(reqs)
             for h, res in zip(handles, results):
@@ -831,7 +842,8 @@ class InferenceService:
             self.flush()               # still queued (or cancelled)
         ev = handle._event
         if ev is not None:
-            ev.wait()
+            with span("await_result"):
+                ev.wait()
 
     def drain(self) -> None:
         """Flush until no request remains queued, then wait for every

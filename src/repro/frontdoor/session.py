@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 import traceback
 from typing import Callable, Optional
 
@@ -52,9 +51,6 @@ class QuerySession:
         self.scope = CancelScope()
         self.status = "queued"          # queued|running|ok|cancelled|error
         self.rows_emitted = 0
-        self.created_s = time.time()
-        self.first_chunk_s: Optional[float] = None
-        self.finished_s: Optional[float] = None
         self._abort = threading.Event()
         # order matters: set the abort flag BEFORE waking gate waiters so
         # a woken acquire() observes it and returns without a grant
@@ -97,8 +93,6 @@ class QuerySession:
                     self.gate.release(self.tenant, cost=float(cost))
                 if chunk is None:
                     break
-                if self.first_chunk_s is None:
-                    self.first_chunk_s = time.time()
                 rows = chunk.rows()
                 self.rows_emitted += len(rows)
                 emit({"type": "chunk", "session": self.id, "seq": seq,
@@ -117,7 +111,6 @@ class QuerySession:
     def _trail(self, emit, status: str, stats, *, plan: Optional[str] = None,
                error: str = "") -> None:
         self.status = status
-        self.finished_s = time.time()
         frame = {"type": "trailer", "session": self.id, "status": status,
                  "rows": self.rows_emitted,
                  "stats": stats_frame_dict(stats)}

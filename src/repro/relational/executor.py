@@ -49,6 +49,9 @@ class ExecStats:
     decode_tokens: int = 0
     prefix_hits: int = 0
     radix_hit_tokens: int = 0       # prompt tokens served from the radix tree
+    decode_steps: int = 0           # decode ticks the engine ran
+    decode_rows: int = 0            # live rows summed over those ticks
+    decode_slots: int = 0           # decode-batch width summed over them
     # cascade accounting (CascadePredictor routes; zero for direct plans)
     proxy_calls: int = 0            # proxy-stage prompts scored
     escalated_calls: int = 0        # expensive-stage calls actually made
@@ -68,6 +71,9 @@ class ExecStats:
     degraded_calls: int = 0         # cascade calls served proxy-only
     backend_timeouts: int = 0       # dispatch batches killed by call timeout
     breaker_rejections: int = 0     # requests shed by an open breaker
+    # real time, like wall_s: summed over the query's dispatch batches, from
+    # the submit of a batch's first request to the start of its executor call
+    queue_wait_s: float = 0.0
 
     @property
     def tokens(self) -> int:
@@ -133,7 +139,6 @@ class PlanExecutor:
         self.stats.out_tokens += s.out_tokens
         self.stats.sim_latency_s += s.sim_latency_s
         self.stats.serial_latency_s += s.serial_latency_s
-        self.stats.wall_s += s.wall_s
         self.stats.cache_hits += s.cache_hits
         self.stats.retries += s.retries
         self.stats.batch_fallbacks += s.batch_fallbacks
@@ -144,6 +149,9 @@ class PlanExecutor:
         self.stats.decode_tokens += s.decode_tokens
         self.stats.prefix_hits += s.prefix_hits
         self.stats.radix_hit_tokens += s.radix_hit_tokens
+        self.stats.decode_steps += s.decode_steps
+        self.stats.decode_rows += s.decode_rows
+        self.stats.decode_slots += s.decode_slots
         self.stats.proxy_calls += s.proxy_calls
         self.stats.escalated_calls += s.escalated_calls
         self.stats.cascade_rows += s.cascade_rows

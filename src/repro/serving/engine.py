@@ -21,13 +21,13 @@ Features mapped from the paper's optimizations:
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.trace import span
 from repro.models import model as MDL
 from repro.models.config import ModelConfig
 from repro.serving import tokenizer as TOK
@@ -50,8 +50,9 @@ class GenStats:
     input_tokens: int = 0
     output_tokens: int = 0
     prefill_tokens: int = 0        # actually prefit through the model
-    decode_steps: int = 0
-    wall_s: float = 0.0
+    decode_steps: int = 0          # ticks: one sampled token per live row
+    decode_rows: int = 0           # live rows summed over the ticks
+    decode_slots: int = 0          # decode-batch width summed over the ticks
     prefix_hits: int = 0
     radix_hit_tokens: int = 0      # prompt tokens served from the radix tree
     cow_copies: int = 0            # pages privatized by copy-on-write forks
@@ -268,28 +269,30 @@ class InferenceEngine:
         """offset = cache slot offset (bucketed prefix length);
         pos_offset = absolute position offset (REAL prefix length — RoPE
         positions must not jump over the prefix bucket padding)."""
-        if pos_offset is None:
-            pos_offset = offset
-        B = len(token_lists)
-        L = _bucket(max(len(t) for t in token_lists))
-        toks = np.full((B, L), TOK.PAD_ID, np.int32)
-        pos = np.zeros((B, L), np.int32)
-        for i, t in enumerate(token_lists):
-            pad = L - len(t)
-            toks[i, pad:] = t                                # left padding
-            pos[i] = np.arange(L) - pad + pos_offset
-            pos[i, :pad] = -1      # pads masked (never overlap the prefix)
-        if cache is None:
-            cache = MDL.init_cache(self.cfg, B, self.max_len)
-            if row_idx_mode:
-                cache["row_idx"] = jnp.zeros((B,), jnp.int32)
-        logits, cache = self._prefill_fn(B, L, offset)(
-            self.params, jnp.asarray(toks), jnp.asarray(pos), cache)
-        if "row_idx" in cache or row_idx_mode:
-            cache = dict(cache)
-            cache["row_idx"] = jnp.full((B,), offset + L, jnp.int32)
-        lens = np.array([pos_offset + len(t) for t in token_lists], np.int32)
-        return np.asarray(logits, np.float32), cache, lens, B * L
+        with span("engine.prefill"):
+            if pos_offset is None:
+                pos_offset = offset
+            B = len(token_lists)
+            L = _bucket(max(len(t) for t in token_lists))
+            toks = np.full((B, L), TOK.PAD_ID, np.int32)
+            pos = np.zeros((B, L), np.int32)
+            for i, t in enumerate(token_lists):
+                pad = L - len(t)
+                toks[i, pad:] = t                            # left padding
+                pos[i] = np.arange(L) - pad + pos_offset
+                pos[i, :pad] = -1  # pads masked (never overlap the prefix)
+            if cache is None:
+                cache = MDL.init_cache(self.cfg, B, self.max_len)
+                if row_idx_mode:
+                    cache["row_idx"] = jnp.zeros((B,), jnp.int32)
+            logits, cache = self._prefill_fn(B, L, offset)(
+                self.params, jnp.asarray(toks), jnp.asarray(pos), cache)
+            if "row_idx" in cache or row_idx_mode:
+                cache = dict(cache)
+                cache["row_idx"] = jnp.full((B,), offset + L, jnp.int32)
+            lens = np.array([pos_offset + len(t) for t in token_lists],
+                            np.int32)
+            return np.asarray(logits, np.float32), cache, lens, B * L
 
     # ------------------------------ page pool ---------------------------------
     def _page_bytes(self) -> int:
@@ -375,7 +378,8 @@ class InferenceEngine:
         Returned pages are retained for the caller (release when done)."""
         if self._radix is None:
             return [], 0
-        pages, n = self._radix.match(ids, limit=limit)
+        with span("engine.radix_match"):
+            pages, n = self._radix.match(ids, limit=limit)
         if n:
             stats.prefix_hits += 1
             stats.radix_hit_tokens += n
@@ -391,10 +395,11 @@ class InferenceEngine:
         nfull = len(ids) // self.page_size
         if nfull == 0:
             return []
-        adopted = self._radix.insert(
-            list(ids[:nfull * self.page_size]), list(pages[:nfull]))
-        if adopted and self.kv_quant == "int8":
-            self._quantize_pages(adopted)
+        with span("engine.radix_insert"):
+            adopted = self._radix.insert(
+                list(ids[:nfull * self.page_size]), list(pages[:nfull]))
+            if adopted and self.kv_quant == "int8":
+                self._quantize_pages(adopted)
         return adopted
 
     # -- warm-state snapshots (core/snapshot.py) -------------------------
@@ -501,20 +506,21 @@ class InferenceEngine:
         a = self._alloc
         if a.free_pages >= need_pages:
             return True
-        for key in list(self._prefix_kv):      # LRU-first residency drop
-            if a.free_pages >= need_pages:
-                break
-            ent = self._prefix_kv[key]
-            # skip entries whose pages an in-flight run still retains:
-            # releasing the memo's reference would free nothing while
-            # permanently discarding the zero-copy residency
-            if ent.pages is not None and \
-                    all(a.refs(p) == 1 for p in ent.pages):
-                a.release(ent.pages)
-                ent.pages = None
-        if a.free_pages < need_pages and self._radix is not None:
-            # radix eviction: LRU leaf nodes with no outside readers
-            self._radix.evict(need_pages - a.free_pages)
+        with span("engine.evict"):
+            for key in list(self._prefix_kv):  # LRU-first residency drop
+                if a.free_pages >= need_pages:
+                    break
+                ent = self._prefix_kv[key]
+                # skip entries whose pages an in-flight run still retains:
+                # releasing the memo's reference would free nothing while
+                # permanently discarding the zero-copy residency
+                if ent.pages is not None and \
+                        all(a.refs(p) == 1 for p in ent.pages):
+                    a.release(ent.pages)
+                    ent.pages = None
+            if a.free_pages < need_pages and self._radix is not None:
+                # radix eviction: LRU leaf nodes with no outside readers
+                self._radix.evict(need_pages - a.free_pages)
         if a.free_pages >= need_pages:
             return True
         if self.page_pool_pages is not None:
@@ -653,51 +659,55 @@ class InferenceEngine:
         np.ndarray (B, NB) page ids.  Returns (logits, lens, prefill_token
         count, extra_out) — extra carries per-row SSM state for hybrid
         models."""
-        B = len(token_lists)
-        L = _bucket(max(len(t) for t in token_lists))
-        toks = np.full((B, L), TOK.PAD_ID, np.int32)
-        pos = np.zeros((B, L), np.int32)
-        for i, t in enumerate(token_lists):
-            pad = L - len(t)
-            toks[i, pad:] = t
-            pos[i] = np.arange(L) - pad + prefix_len
-            pos[i, :pad] = -1
-        npre = len(prefix_pages)
-        cache = dict(self._pool, idx=jnp.int32(0))
-        if extra:
-            cache.update(extra)
-        key = ("paged", B, L, table_rows.shape[1], npre)
-        if key not in self._prefill_cache:
-            cfg = self.cfg
+        with span("engine.prefill"):
+            B = len(token_lists)
+            L = _bucket(max(len(t) for t in token_lists))
+            toks = np.full((B, L), TOK.PAD_ID, np.int32)
+            pos = np.zeros((B, L), np.int32)
+            for i, t in enumerate(token_lists):
+                pad = L - len(t)
+                toks[i, pad:] = t
+                pos[i] = np.arange(L) - pad + prefix_len
+                pos[i, :pad] = -1
+            npre = len(prefix_pages)
+            cache = dict(self._pool, idx=jnp.int32(0))
+            if extra:
+                cache.update(extra)
+            key = ("paged", B, L, table_rows.shape[1], npre)
+            if key not in self._prefill_cache:
+                cfg = self.cfg
 
-            # block table / prefix table / quant flags ride OUTSIDE the
-            # donated cache: they are rebuilt host-side every call,
-            # donation buys nothing
-            def paged_prefill_step(params, tokens, positions, cache, bt,
-                                   ptab, plen, qf):
-                cache = dict(cache, block_tables=bt, prefix_table=ptab,
-                             prefix_len=plen)
-                if qf is not None:
-                    cache["quant_flags"] = qf
-                logits, cache = MDL.forward(
-                    cfg, params, {"tokens": tokens, "positions": positions},
-                    mode="prefill", cache=cache, remat=False, last_only=True)
-                return logits[:, -1], cache
+                # block table / prefix table / quant flags ride OUTSIDE the
+                # donated cache: they are rebuilt host-side every call,
+                # donation buys nothing
+                def paged_prefill_step(params, tokens, positions, cache, bt,
+                                       ptab, plen, qf):
+                    cache = dict(cache, block_tables=bt, prefix_table=ptab,
+                                 prefix_len=plen)
+                    if qf is not None:
+                        cache["quant_flags"] = qf
+                    logits, cache = MDL.forward(
+                        cfg, params,
+                        {"tokens": tokens, "positions": positions},
+                        mode="prefill", cache=cache, remat=False,
+                        last_only=True)
+                    return logits[:, -1], cache
 
-            self._prefill_cache[key] = jax.jit(paged_prefill_step,
-                                               donate_argnums=(3,))
-        qf = None if self._quant_flags is None \
-            else jnp.asarray(self._quant_flags)
-        logits, out = self._prefill_cache[key](
-            self.params, jnp.asarray(toks), jnp.asarray(pos), cache,
-            jnp.asarray(np.ascontiguousarray(table_rows)),
-            jnp.asarray(np.asarray(prefix_pages, np.int32).reshape(npre)),
-            jnp.int32(prefix_len), qf)
-        for kk in self._pool:
-            self._pool[kk] = out[kk]
-        extra_out = {k: out[k] for k in ("conv", "h") if k in out}
-        lens = np.array([prefix_len + len(t) for t in token_lists], np.int32)
-        return np.asarray(logits, np.float32), lens, B * L, extra_out
+                self._prefill_cache[key] = jax.jit(paged_prefill_step,
+                                                   donate_argnums=(3,))
+            qf = None if self._quant_flags is None \
+                else jnp.asarray(self._quant_flags)
+            logits, out = self._prefill_cache[key](
+                self.params, jnp.asarray(toks), jnp.asarray(pos), cache,
+                jnp.asarray(np.ascontiguousarray(table_rows)),
+                jnp.asarray(np.asarray(prefix_pages, np.int32).reshape(npre)),
+                jnp.int32(prefix_len), qf)
+            for kk in self._pool:
+                self._pool[kk] = out[kk]
+            extra_out = {k: out[k] for k in ("conv", "h") if k in out}
+            lens = np.array([prefix_len + len(t) for t in token_lists],
+                            np.int32)
+            return np.asarray(logits, np.float32), lens, B * L, extra_out
 
     def paged_decode(self, toks, positions, table, num_blocks: int, *,
                      extra: Optional[dict] = None):
@@ -736,6 +746,8 @@ class InferenceEngine:
         """Apply one sampled token per not-yet-done row: grammar advance,
         EOS, completion + per-tick stats. Shared by the dense and paged
         generate loops so their semantics cannot drift."""
+        stats.decode_rows += int((~done).sum())
+        stats.decode_slots += len(done)
         for i in range(len(done)):
             if done[i]:
                 continue
@@ -762,7 +774,12 @@ class InferenceEngine:
         """Generate for a batch of prompts. If shared_prefix is given it is
         prefilled once and KV-reused across rows (prompts are then the
         suffixes). Grammar-constrained when grammar(s) provided."""
-        t0 = time.time()
+        with span("engine.run"):
+            return self._generate(prompts, grammar, grammars,
+                                  max_new_tokens, temperature, shared_prefix)
+
+    def _generate(self, prompts, grammar, grammars, max_new_tokens,
+                  temperature, shared_prefix) -> GenResult:
         stats = GenStats(calls=1)
         B = len(prompts)
         gs = grammars or ([grammar] * B if grammar else [None] * B)
@@ -771,7 +788,6 @@ class InferenceEngine:
         if self.kv_layout == "paged":
             texts = self._generate_paged(prompts, gs, states, max_new_tokens,
                                          temperature, shared_prefix, stats)
-            stats.wall_s = time.time() - t0
             self.total.add(stats)
             return GenResult(texts, stats)
 
@@ -799,16 +815,20 @@ class InferenceEngine:
         positions = lens.copy()
 
         for step in range(max_new_tokens):
-            toks = self._sample(logits, gs, states, temperature)
-            self._consume_tokens(toks, gs, states, out_tokens, done, stats)
-            if done.all():
-                break
-            lg, cache = decode(self.params, jnp.asarray(toks[:, None]),
-                               jnp.asarray(positions[:, None]), cache)
-            logits = np.asarray(lg, np.float32)
-            positions += 1
+            with span("engine.tick"):
+                toks = self._sample(logits, gs, states, temperature)
+                with span("engine.advance"):
+                    self._consume_tokens(toks, gs, states, out_tokens, done,
+                                         stats)
+                if done.all():
+                    break
+                with span("engine.step"):
+                    lg, cache = decode(self.params,
+                                       jnp.asarray(toks[:, None]),
+                                       jnp.asarray(positions[:, None]), cache)
+                    logits = np.asarray(lg, np.float32)
+                positions += 1
 
-        stats.wall_s = time.time() - t0
         self.total.add(stats)
         return GenResult([TOK.decode(t) for t in out_tokens], stats)
 
@@ -919,15 +939,18 @@ class InferenceEngine:
             positions = lens.copy()
 
             for step in range(max_new_tokens):
-                toks = self._sample(logits, gs, states, temperature)
-                self._consume_tokens(toks, gs, states, out_tokens, done,
-                                     stats)
-                if done.all():
-                    break
-                nb = self.active_blocks(positions[~done])
-                logits, extra = self.paged_decode(toks, positions, table, nb,
-                                                  extra=extra)
-                positions += 1
+                with span("engine.tick"):
+                    toks = self._sample(logits, gs, states, temperature)
+                    with span("engine.advance"):
+                        self._consume_tokens(toks, gs, states, out_tokens,
+                                             done, stats)
+                    if done.all():
+                        break
+                    with span("engine.step"):
+                        nb = self.active_blocks(positions[~done])
+                        logits, extra = self.paged_decode(
+                            toks, positions, table, nb, extra=extra)
+                    positions += 1
         finally:
             # errors must not leak refcounts: a pinned pool would shrink
             # permanently
@@ -942,26 +965,29 @@ class InferenceEngine:
     # ------------------------------- sampling ---------------------------------
     def _sample(self, logits: np.ndarray, gs, states, temperature: float
                 ) -> np.ndarray:
-        B, V = logits.shape
-        mask = np.ones((B, V), np.int8)
-        for i, (g, st) in enumerate(zip(gs, states)):
-            if g is not None:
-                m = g.mask(st)
-                mask[i, :] = 0
-                mask[i, :len(m)] = m
-        noise = None
-        if temperature > 0:
-            u = self._rng.uniform(1e-9, 1.0, size=(B, V))
-            noise = -np.log(-np.log(u))
-        if self.use_pallas_sampler:
-            from repro.kernels import ops as KOPS
-            return np.asarray(KOPS.constrained_sample(
-                jnp.asarray(logits), jnp.asarray(mask),
-                None if noise is None else jnp.asarray(noise),
-                temperature=max(temperature, 1e-6) if temperature > 0 else 1.0,
-                block_v=256))
-        x = logits / (temperature if temperature > 0 else 1.0)
-        if noise is not None:
-            x = x + noise
-        x = np.where(mask != 0, x, NEG_INF)
-        return np.argmax(x, axis=-1).astype(np.int32)
+        with span("engine.sample"):
+            B, V = logits.shape
+            with span("engine.mask"):
+                mask = np.ones((B, V), np.int8)
+                for i, (g, st) in enumerate(zip(gs, states)):
+                    if g is not None:
+                        m = g.mask(st)
+                        mask[i, :] = 0
+                        mask[i, :len(m)] = m
+            noise = None
+            if temperature > 0:
+                u = self._rng.uniform(1e-9, 1.0, size=(B, V))
+                noise = -np.log(-np.log(u))
+            if self.use_pallas_sampler:
+                from repro.kernels import ops as KOPS
+                return np.asarray(KOPS.constrained_sample(
+                    jnp.asarray(logits), jnp.asarray(mask),
+                    None if noise is None else jnp.asarray(noise),
+                    temperature=(max(temperature, 1e-6) if temperature > 0
+                                 else 1.0),
+                    block_v=256))
+            x = logits / (temperature if temperature > 0 else 1.0)
+            if noise is not None:
+                x = x + noise
+            x = np.where(mask != 0, x, NEG_INF)
+            return np.argmax(x, axis=-1).astype(np.int32)

@@ -21,7 +21,6 @@ Two KV layouts (engine.kv_layout):
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import Counter
 from typing import Dict, List, Optional, Sequence
 
@@ -29,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.trace import span
 from repro.models import model as MDL
 from repro.serving import tokenizer as TOK
 from repro.serving.engine import GenStats, InferenceEngine, NEG_INF
@@ -133,7 +133,6 @@ class ContinuousBatcher:
         prefills it per slot (replication — the old behavior), the paged
         layout prefills it once into shared pool pages."""
         st = GenStats(calls=1)
-        t0 = time.time()
         reqs = list(requests)
         paged = self.engine.kv_layout == "paged"
         jobs: List[_Job] = []
@@ -142,12 +141,12 @@ class ContinuousBatcher:
             ns = max(1, r.n_samples)
             grp = _ForkGroup(ns - 1) if (ns > 1 and paged) else None
             jobs.extend(_Job(r, k, grp) for k in range(ns))
-        if paged:
-            self._run_paged(jobs, temperature, shared_prefix, st)
-        else:
-            self._run_dense(jobs, temperature, shared_prefix, st)
+        with span("engine.run"):
+            if paged:
+                self._run_paged(jobs, temperature, shared_prefix, st)
+            else:
+                self._run_dense(jobs, temperature, shared_prefix, st)
         self._reduce(reqs, jobs)
-        st.wall_s = time.time() - t0
         self.stats.add(st)
         self.engine.total.add(st)
         return reqs
@@ -181,6 +180,9 @@ class ContinuousBatcher:
         token-budget eviction, completion. Shared by both layouts so their
         tick semantics (and the pinned byte-equality) cannot drift.
         Returns the number of slots that finished."""
+        st.decode_steps += 1
+        st.decode_rows += len(live)
+        st.decode_slots += len(active)
         done = 0
         for b in live:
             r = active[b]
@@ -231,15 +233,16 @@ class ContinuousBatcher:
             st.input_tokens += len(ids)
             # splice sequence 0 of c1 into slot b of the live cache
             new = dict(cache)
-            for k, v in c1.items():
-                if k == "idx":
-                    continue
-                tgt = jnp.asarray(cache[k])
-                src = jnp.asarray(v)
-                if k in ("k", "v", "conv", "h"):          # (L, B, ...)
-                    new[k] = tgt.at[:, b].set(src[:, 0])
-                elif k in ("slot_pos", "row_idx"):        # (B, ...)
-                    new[k] = tgt.at[b].set(src[0])
+            with span("engine.splice"):
+                for k, v in c1.items():
+                    if k == "idx":
+                        continue
+                    tgt = jnp.asarray(cache[k])
+                    src = jnp.asarray(v)
+                    if k in ("k", "v", "conv", "h"):          # (L, B, ...)
+                        new[k] = tgt.at[:, b].set(src[:, 0])
+                    elif k in ("slot_pos", "row_idx"):        # (B, ...)
+                        new[k] = tgt.at[b].set(src[0])
             active[b] = req
             states[b] = req.grammar.init_state() if req.grammar else None
             outs[b] = []
@@ -250,36 +253,37 @@ class ContinuousBatcher:
 
         decode = eng._decode_fn()
         done_count = 0
-        ticks = 0
         while done_count < len(reqs):
-            # refill free slots
-            for b in range(B):
-                if active[b] is None and queue:
-                    cache = fill_slot(b, queue.pop(0), cache)
-            live = [b for b in range(B) if active[b] is not None]
-            if not live:
-                break
+            with span("engine.tick"):
+                # refill free slots
+                for b in range(B):
+                    if active[b] is None and queue:
+                        with span("engine.fill"):
+                            cache = fill_slot(b, queue.pop(0), cache)
+                live = [b for b in range(B) if active[b] is not None]
+                if not live:
+                    break
 
-            gs = [active[b].grammar if active[b] else None for b in range(B)]
-            toks = eng._sample(logits, gs, states, temperature)
-            done_count += self._advance_live(live, active, states, outs,
-                                             budgets, toks, st, logits,
-                                             lambda b: None)
+                gs = [active[b].grammar if active[b] else None
+                      for b in range(B)]
+                toks = eng._sample(logits, gs, states, temperature)
+                with span("engine.advance"):
+                    done_count += self._advance_live(
+                        live, active, states, outs, budgets, toks, st,
+                        logits, lambda b: None)
 
-            if done_count >= len(reqs):
-                break
-            if not any(a is not None for a in active):
-                continue           # all finished this tick; refill next
-            lg, cache = decode(eng.params, jnp.asarray(toks[:, None]),
-                               jnp.asarray(positions[:, None]), cache)
-            lgn = np.asarray(lg, np.float32)
-            for b in range(B):
-                if active[b] is not None:
-                    logits[b] = lgn[b]
-            positions += 1
-            ticks += 1
-
-        st.decode_steps += ticks
+                if done_count >= len(reqs):
+                    break
+                if not any(a is not None for a in active):
+                    continue       # all finished this tick; refill next
+                with span("engine.step"):
+                    lg, cache = decode(eng.params, jnp.asarray(toks[:, None]),
+                                       jnp.asarray(positions[:, None]), cache)
+                    lgn = np.asarray(lg, np.float32)
+                for b in range(B):
+                    if active[b] is not None:
+                        logits[b] = lgn[b]
+                positions += 1
 
     # ------------------------------- paged ------------------------------------
     def _run_paged(self, reqs: List[_Job], temperature: float,
@@ -443,47 +447,51 @@ class ContinuousBatcher:
                 st.cow_copies += len(srcs)
 
         done_count = 0
-        ticks = 0
         try:
             while done_count < len(reqs):
-                stalled = False
-                for b in range(B):
-                    if active[b] is None and queue and not stalled:
-                        if fill_slot(b, queue[0]):
-                            queue.pop(0)
-                        else:
-                            stalled = True
-                live = [b for b in range(B) if active[b] is not None]
-                if not live:
-                    if queue:
-                        raise RuntimeError(
-                            f"page pool ({eng.page_pool_pages} pages) too "
-                            f"small for even one request")
-                    break
+                with span("engine.tick"):
+                    stalled = False
+                    for b in range(B):
+                        if active[b] is None and queue and not stalled:
+                            with span("engine.fill"):
+                                filled = fill_slot(b, queue[0])
+                            if filled:
+                                queue.pop(0)
+                            else:
+                                stalled = True
+                    live = [b for b in range(B) if active[b] is not None]
+                    if not live:
+                        if queue:
+                            raise RuntimeError(
+                                f"page pool ({eng.page_pool_pages} pages) "
+                                f"too small for even one request")
+                        break
 
-                gs = [active[b].grammar if active[b] else None
-                      for b in range(B)]
-                toks = eng._sample(logits, gs, states, temperature)
-                done_count += self._advance_live(live, active, states, outs,
-                                                 budgets, toks, st, logits,
-                                                 free_slot)
+                    gs = [active[b].grammar if active[b] else None
+                          for b in range(B)]
+                    toks = eng._sample(logits, gs, states, temperature)
+                    with span("engine.advance"):
+                        done_count += self._advance_live(
+                            live, active, states, outs, budgets, toks, st,
+                            logits, free_slot)
 
-                if done_count >= len(reqs):
-                    break
-                live = [b for b in range(B) if active[b] is not None]
-                if not live:
-                    continue           # all finished this tick; refill next
-                cow_guard(live)
-                nb = eng.active_blocks(positions[live])
-                lgn, extra_out = eng.paged_decode(toks, positions, table, nb,
-                                                  extra=extra)
-                if extra:
-                    extra = extra_out
-                for b in range(B):
-                    if active[b] is not None:
-                        logits[b] = lgn[b]
-                positions += 1
-                ticks += 1
+                    if done_count >= len(reqs):
+                        break
+                    live = [b for b in range(B) if active[b] is not None]
+                    if not live:
+                        continue       # all finished this tick; refill next
+                    with span("engine.cow"):
+                        cow_guard(live)
+                    with span("engine.step"):
+                        nb = eng.active_blocks(positions[live])
+                        lgn, extra_out = eng.paged_decode(
+                            toks, positions, table, nb, extra=extra)
+                    if extra:
+                        extra = extra_out
+                    for b in range(B):
+                        if active[b] is not None:
+                            logits[b] = lgn[b]
+                    positions += 1
         finally:
             # errors must not leak slot pages, fork-group leases, or the
             # prefix retain: a pinned pool would shrink permanently
@@ -498,6 +506,5 @@ class ContinuousBatcher:
                 g.release(eng)
             if pages_pre:
                 eng.release_pages(pages_pre)
-        st.decode_steps += ticks
         eng._note_kv()
         st.kv_bytes = eng.kv_peak_bytes

@@ -93,12 +93,13 @@ def drain_stream(stream):
 
 
 def stream_stats_dict(stats) -> dict:
-    """ExecStats as a comparable dict: drop wall_s (real time, the one
-    honest nondeterminism) — everything else must match exactly across
-    interleavings and worker counts."""
+    """ExecStats as a comparable dict: drop wall_s and queue_wait_s (real
+    time, the honest nondeterminism) — everything else must match exactly
+    across interleavings and worker counts."""
     import dataclasses as _dc
     d = _dc.asdict(stats)
     d.pop("wall_s")
+    d.pop("queue_wait_s")
     return d
 
 

@@ -120,6 +120,7 @@ def _norm_explain(text: str) -> str:
 def _stats_dict(stats):
     d = dataclasses.asdict(stats)
     d.pop("wall_s")
+    d.pop("queue_wait_s")
     return d
 
 

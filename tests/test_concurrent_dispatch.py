@@ -67,7 +67,8 @@ Q_STACKED_SELECTS = ("SELECT a FROM T WHERE LLM fastm (PROMPT 'p "
 
 def _stats_dict(stats):
     d = dataclasses.asdict(stats)
-    d.pop("wall_s")                    # real time: the one honest exception
+    d.pop("wall_s")                    # real time: the honest exceptions
+    d.pop("queue_wait_s")
     return d
 
 

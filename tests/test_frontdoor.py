@@ -185,7 +185,7 @@ def test_http_stream_rows_and_exec_stats_trailer():
         # the trailer carries the same ExecStats the Python API reports
         ref = db.sql(q("one"))                 # fully prompt-cached rerun
         assert set(trailer["stats"]) == (
-            set(stream_stats_dict(ref.stats)) | {"wall_s"})
+            set(stream_stats_dict(ref.stats)) | {"wall_s", "queue_wait_s"})
         assert trailer["stats"]["llm_calls"] == 24 // 4
         assert trailer["stats"]["cancelled"] is False
         assert trailer["rows"] == 24
