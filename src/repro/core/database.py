@@ -474,9 +474,10 @@ class IPDB:
         # Layouts come from the LIVE engines (a model can override the
         # session default per-entry); the option is the fallback before
         # any jax engine exists.
-        hits = prefill = decoded = radix_toks = 0
+        hits = prefill = decoded = radix_toks = param_bytes = 0
         used = total = hwm = 0
         for eng in self._jax_engines.values():
+            param_bytes += eng.param_bytes
             hits += eng.total.prefix_hits
             prefill += eng.total.prefill_tokens
             decoded += eng.total.output_tokens
@@ -490,10 +491,10 @@ class IPDB:
             or [str(o.get("kv_layout", "dense"))]
         line += ("\nEngine kv_layout={} kv_page_size={} kv_quant={} "
                  "prefix_hits={} radix_hit_tokens={} prefill_tokens={} "
-                 "decode_tokens={}".format(
+                 "decode_tokens={} param_bytes={}".format(
                      ",".join(layouts), o.get("kv_page_size", 64),
                      o.get("kv_quant", "none"), hits, radix_toks,
-                     prefill, decoded))
+                     prefill, decoded, param_bytes))
         line += "\npool: {}/{} pages, hwm={}".format(used, total, hwm)
         return line
 

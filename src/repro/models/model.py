@@ -100,6 +100,47 @@ def _layer_shapes(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
     return out
 
 
+# Leaves the forward reads only as `.astype(compute_dtype)`. Every other leaf
+# is read in float32 (norm scales and biases, moe.router, ssm.dt_bias,
+# ssm.A_log, ssm.D) and keeps its dtype.
+_COMPUTE_DTYPE_LEAVES = frozenset({
+    "embed", "lm_head",
+    "attn.wq", "attn.wk", "attn.wv", "attn.wo", "attn.bq", "attn.bk",
+    "attn.bv",
+    "mlp.w_gate", "mlp.w_up", "mlp.w_down",
+    "mlp.w_in", "mlp.b_in", "mlp.w_out", "mlp.b_out",
+    "moe.w_gate", "moe.w_up", "moe.w_down",
+    "ssm.in_x", "ssm.in_z", "ssm.conv_w", "ssm.conv_b", "ssm.x_proj",
+    "ssm.dt_proj", "ssm.out_proj",
+})
+
+
+def serving_params(cfg: ModelConfig, params: PyTree) -> PyTree:
+    """The tree the serving step programs take: every leaf the forward reads
+    only as `.astype(compute_dtype)` stored in the compute dtype, so the
+    cast is made once here and not on every launch. The matmuls see the
+    same values. Maps arrays to arrays and ShapeDtypeStructs to
+    ShapeDtypeStructs; returns `params` itself when the two dtypes agree."""
+    cd = _dt(cfg.compute_dtype)
+    if _dt(cfg.param_dtype) == cd:
+        return params
+
+    def cast(path, x):
+        if path[-1].key not in _COMPUTE_DTYPE_LEAVES or x.dtype == cd:
+            return x
+        if isinstance(x, jax.ShapeDtypeStruct):
+            return x.update(dtype=cd)
+        return x.astype(cd)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
+def tree_bytes(tree: PyTree) -> int:
+    """Device bytes of a tree of arrays or ShapeDtypeStructs."""
+    return sum(math.prod(x.shape) * jnp.dtype(x.dtype).itemsize
+               for x in jax.tree.leaves(tree))
+
+
 def param_specs(cfg: ModelConfig) -> PyTree:
     """ShapeDtypeStructs for the full parameter tree (stacked layers)."""
     m, vp, pd = cfg.d_model, cfg.padded_vocab, _dt(cfg.param_dtype)
@@ -118,8 +159,12 @@ def param_specs(cfg: ModelConfig) -> PyTree:
     return tree
 
 
-def init_params(cfg: ModelConfig, key: jax.Array) -> PyTree:
-    specs = param_specs(cfg)
+def init_params(cfg: ModelConfig, key: jax.Array,
+                specs: Optional[PyTree] = None) -> PyTree:
+    """Seeded weights. Each leaf is drawn in float32 and cast to its spec's
+    dtype; `specs` (default `param_specs(cfg)`) may be its serving tree, so
+    that no float32 copy of a compute-dtype leaf outlives its own draw."""
+    specs = param_specs(cfg) if specs is None else specs
     flat_paths, treedef = jax.tree_util.tree_flatten_with_path(specs)
     keys = jax.random.split(key, len(flat_paths))
     vals = []

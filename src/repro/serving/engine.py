@@ -167,8 +167,15 @@ class InferenceEngine:
             assert cfg.has_attention, "paged KV layout needs attention"
         self.cfg = cfg
         self.max_len = max_len
-        self.params = params if params is not None else \
-            MDL.init_params(cfg, jax.random.PRNGKey(seed))
+        #: the step programs' one weight tree (MDL.serving_params): leaves
+        #: the forward only casts are held in the compute dtype
+        if params is None:
+            params = MDL.init_params(
+                cfg, jax.random.PRNGKey(seed),
+                MDL.serving_params(cfg, MDL.param_specs(cfg)))
+        self.params = MDL.serving_params(cfg, params)
+        #: device bytes of that tree (EXPLAIN `-- dispatch --`)
+        self.param_bytes = MDL.tree_bytes(self.params)
         self.use_pallas_sampler = use_pallas_sampler
         self.use_pallas_decode = use_pallas_decode
         self.kv_layout = kv_layout

@@ -311,6 +311,7 @@ def test_explain_dispatch_shows_kv_layout():
     assert "prefix_hits=" in out and "prefill_tokens=" in out
     assert "radix_hit_tokens=" in out and "kv_quant=" in out
     assert "pool: 0/0 pages, hwm=0" in out   # oracle backend: no jax pool
+    assert "param_bytes=0" in out            # ... and no jax weights
     db.close()
 
 

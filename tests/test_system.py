@@ -137,6 +137,11 @@ def test_real_jax_executor_end_to_end():
     assert len(r.table) == 3
     assert all(isinstance(c, str) for c in r.table.column("color"))
     assert r.stats.llm_calls == 2          # ceil(3 unique / batch 2)
+    # EXPLAIN names the device bytes of the engine's weight tree
+    eng, = d._jax_engines.values()
+    assert eng.param_bytes > 0
+    assert f"param_bytes={eng.param_bytes}" in d.explain(
+        "SELECT name FROM Items")
 
 
 def test_jax_model_options_pick_the_published_config(monkeypatch):

@@ -10,6 +10,7 @@ same tests and only the worker running this file loads the TPU library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -107,11 +108,24 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _engine(sharding, layout="dense"):
+    # params as shapes: the engine allocates nothing until it serves
+    return InferenceEngine(CFG, params=_on(sharding, MDL.param_specs(CFG)),
+                           max_len=MAX_LEN, kv_layout=layout, page_size=PAGE)
+
+
+def _float32_weights(compiled):
+    """The program's float32 weight arguments (matrices of the `params`
+    argument, not norm vectors) and its converts of a `params` argument."""
+    text = compiled.as_text()
+    return (re.findall(r"%(params__\w+)(?:\.\d+)? = f32\[\d+(?:,\d+)+\]",
+                       text)
+            + re.findall(r"\b(convert\(%params__\w+)", text))
+
+
 @pytest.mark.parametrize("layout", ["dense", "paged"])
 def test_full_width_decode_step_fits_one_chip(one_chip, layout):
-    # params as shapes: the engine allocates nothing until it serves
-    eng = InferenceEngine(CFG, params=_on(one_chip, MDL.param_specs(CFG)),
-                          max_len=MAX_LEN, kv_layout=layout, page_size=PAGE)
+    eng = _engine(one_chip, layout)
     tok = jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32, sharding=one_chip)
     if layout == "dense":
         cache = _on(one_chip, MDL.cache_specs(CFG, SLOTS, MAX_LEN,
@@ -123,7 +137,21 @@ def test_full_width_decode_step_fits_one_chip(one_chip, layout):
         bt = jax.ShapeDtypeStruct((SLOTS, NB), jnp.int32, sharding=one_chip)
         lowered = eng._decode_fn_paged(NB).lower(eng.params, tok, tok, cache,
                                                  bt, None)
-    mem = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    # the weights arrive in bf16: no step converts a float32 weight
+    assert _float32_weights(compiled) == []
+    mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    # fp32 weights alone are ~4.7 GB; the whole step must fit the chip
+    # bf16 weights are ~2.4 GB of the arguments, the KV cache ~2.1 GB more;
+    # the whole step must fit the chip
     assert 4e9 < used < HBM_BYTES, used
+
+
+def test_full_width_prefill_step_takes_bf16_weights(one_chip):
+    eng = _engine(one_chip)
+    tok = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
+    cache = _on(one_chip, MDL.cache_specs(CFG, 1, MAX_LEN))
+    compiled = eng._prefill_fn(1, 512, 0).lower(eng.params, tok, tok,
+                                                cache).compile()
+    assert _float32_weights(compiled) == []
+    assert eng.param_bytes == MDL.tree_bytes(eng.params) < 2.5e9
