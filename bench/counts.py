@@ -1,4 +1,5 @@
-"""Operations and bytes of the dense decoder family, from its sizes alone.
+"""Operations and bytes of the dense family only (named by
+``bench/families/dense.py``), from its sizes alone.
 
 The sizes come from a configuration file under ``bench/configs`` (or any
 object with the same attribute names, such as the program's ``ModelConfig``).

@@ -1,8 +1,10 @@
 """One run of one benchmark cell: set-up, the measured window, the checks
 that decide ``correct``, and the metrics.
 
-The cell names a configuration (``bench/configs/<config>.json``) and a
-traffic mix (``bench/traffic/<mix>.json``); each metric is read by
+The cell names a configuration (``bench/configs/<config>.json``), whose
+``family`` key names its architecture's reference model and counts
+(``bench/families/<family>.py``), and a traffic mix
+(``bench/traffic/<mix>.json``); each metric is read by
 ``bench/metrics/<metric>.py`` and each cell's comparison limits are in
 ``bench/limits/<cell>.json``.  Nothing here is specific to one of them.
 
@@ -11,7 +13,7 @@ The window drives the user's entry points: ``IPDB.sql`` in a closed loop,
 executor's dispatch (``JaxExecutor.complete_many``) records every prompt and
 every answer the model served; the checks compare the SQL results with those
 answers and, after the program's state is freed, a sample of the answers
-with the plain reference model (``reference.py``).
+with the family's plain reference model.
 """
 from __future__ import annotations
 
@@ -32,7 +34,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from bench import counts, peaks, reference, trace_reduce, traffic
+from bench import peaks, reference, trace_reduce, traffic
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -68,20 +70,40 @@ def load_benchmark(root: Path = ROOT) -> dict:
         return json.load(f)
 
 
-def load_reader(name: str, bench_dir: Path = BENCH) -> Callable:
-    """``bench/metrics/<name>.py``'s ``read(ctx)``."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+def _load_module(name: str, path: Path):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str, bench_dir: Path = BENCH) -> Callable:
+    """``bench/metrics/<name>.py``'s ``read(ctx)``."""
+    return _load_module(f"bench_metric_{name}",
+                        bench_dir / "metrics" / f"{name}.py").read
+
+
+def load_family(cfg: dict, bench_dir: Path = BENCH, where: str = ""):
+    """``bench/families/<family>.py`` for the configuration's ``family``
+    key: its ``KEYS``, reference model and ``Dims`` (the interface is in
+    ``bench/families/dense.py``).  ``where`` names the configuration's file
+    in the errors; there is no default family."""
+    where = where or repr(cfg.get("name"))
+    family = cfg.get("family")
+    if not family:
+        raise ValueError(f"configuration {where} has no 'family' key: it "
+                         f"names its bench/families/<family>.py")
+    path = bench_dir / "families" / f"{family}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"'family': {family!r} of configuration "
+                                f"{where} names {path}, which is not there")
+    return _load_module(f"bench_family_{family}", path)
 
 
 def cell_plan(spec: dict, workload: str, root: Path = ROOT) -> dict:
     """Everything one cell needs, found by the names in BENCHMARK.json:
-    its configuration file, its mix's data file, its metrics' readers and
-    its limits, all under ``root``."""
+    its configuration file and that file's family, its mix's data file, its
+    metrics' readers and its limits, all under ``root``."""
     bench_dir = root / "bench"
     wl = {w["name"]: w for w in spec["workloads"]}.get(workload)
     if wl is None:
@@ -89,6 +111,7 @@ def cell_plan(spec: dict, workload: str, root: Path = ROOT) -> dict:
     entry = {c["name"]: c for c in spec["configs"]}[wl["config"]]
     with open(root / entry["file"]) as f:
         cfg = json.load(f)
+    family = load_family(cfg, bench_dir, entry["file"])
     e2e = [m for m in spec["end_to_end"]
            if workload in m.get("workloads", [workload])]
     reported = {m["name"] for m in e2e}
@@ -99,7 +122,7 @@ def cell_plan(spec: dict, workload: str, root: Path = ROOT) -> dict:
         limits = json.load(f)
     readers = {m["name"]: load_reader(m["name"], bench_dir)
                for m in e2e + layer}
-    return {"workload": wl, "config": cfg,
+    return {"workload": wl, "config": cfg, "family": family,
             "mix": traffic.load(wl["traffic"], bench_dir),
             "end_to_end": e2e, "per_layer": layer, "readers": readers,
             "limits": limits, "chips": int(wl["chips"])}
@@ -121,21 +144,23 @@ def program_config(cfg: dict, smoke: bool):
     return C.get_config(arch)
 
 
-def reference_config(cfg: dict, smoke: bool) -> dict:
+def reference_config(cfg: dict, smoke: bool, family) -> dict:
     """The sizes the reference runs at: the file's, or the smoke variant's
-    (tests only) with the file's published norm and rope settings."""
+    (tests only: the family's ``KEYS``) with the file's published norm and
+    rope settings."""
     if not smoke:
         return cfg
     pc = program_config(cfg, smoke)
     out = dict(cfg)
-    out.update({k: getattr(pc, k) for k in counts.KEYS})
+    out.update({k: getattr(pc, k) for k in family.KEYS})
     return out
 
 
-def check_program_config(cfg: dict) -> None:
-    """The program's configuration must be the one the file states."""
+def check_program_config(cfg: dict, family) -> None:
+    """The program's configuration must be the one the file states: the
+    family's ``KEYS`` and the norm, rope and dtype keys."""
     pc = program_config(cfg, smoke=False)
-    bad = {k: (getattr(pc, k), cfg[k]) for k in counts.KEYS
+    bad = {k: (getattr(pc, k), cfg[k]) for k in family.KEYS
            if getattr(pc, k) != cfg[k]}
     for k in ("norm_type", "rope_theta", "param_dtype", "compute_dtype"):
         if getattr(pc, k) != cfg[k]:
@@ -326,7 +351,7 @@ def build(plan: dict, seed: int, seconds: float, *, traced: bool,
     from repro.relational.table import Table
     cfg, mix = plan["config"], plan["mix"]
     if not smoke:
-        check_program_config(cfg)
+        check_program_config(cfg, plan["family"])
     run = Run(plan)
     tab = mix["table"]
     seen: set = set()
@@ -590,9 +615,10 @@ def sample_requests(reqs: List[dict], seed: int) -> List[dict]:
     return pick
 
 
-def compare_logits(sample: List[dict], cfg: dict, seed: int, *,
+def compare_logits(sample: List[dict], cfg: dict, seed: int, family, *,
                    control: bool = False) -> dict:
-    """The served requests against the float32 reference, re-run over each
+    """The served requests against the family's float32 reference
+    (``family.make_weights``, ``family.logits_at``), re-run over each
     prompt and its served tokens.  ``gap``: the widest amount by which a
     served token's reference logit lies below the reference's best allowed
     token.  ``err``: the largest difference between a logit the program
@@ -600,7 +626,7 @@ def compare_logits(sample: List[dict], cfg: dict, seed: int, *,
     id.  With ``control``, also ``control_gap`` and ``control_err``: the
     same two numbers for the float8 reference put in the program's place,
     at the same positions of the same requests."""
-    w = reference.make_weights(cfg, model_seed(seed))
+    w = family.make_weights(cfg, model_seed(seed))
     out = {"gap": 0.0, "err": 0.0, "control_gap": 0.0, "control_err": 0.0,
            "positions": 0, "requests": len(sample), "off_grammar": 0,
            "unrecorded": 0}
@@ -620,13 +646,13 @@ def compare_logits(sample: List[dict], cfg: dict, seed: int, *,
         idx = [i for i, _ in ch]
         allowed = [a for _, a in ch]
         picks = np.array([served[i] for i in idx])
-        ref = reference.logits_at(w, cfg, tokens, at)[:, :reference.BYTE_IDS]
+        ref = family.logits_at(w, cfg, tokens, at)[:, :reference.BYTE_IDS]
         out["gap"] = max(out["gap"], reference.widest_gap(ref[idx], picks,
                                                           allowed))
         out["err"] = max(out["err"], float(np.abs(got - ref).max()))
         out["positions"] += len(idx)
         if control:
-            low = reference.logits_at(w, cfg, tokens, at, fp8=True)[
+            low = family.logits_at(w, cfg, tokens, at, fp8=True)[
                 :, :reference.BYTE_IDS]
             lp = np.array([a[int(np.argmax(row[list(a)]))]
                            for row, a in zip(low[idx], allowed)])
@@ -658,8 +684,7 @@ def run_cell(plan: dict, seed: int, seconds: float, trace: bool, *,
     ``control``, the float8 reference's logits are judged in the program's
     place, and ``out["control"]`` holds both sides' readings."""
     import jax
-    mix = plan["mix"]
-    cfg = plan["config"]
+    mix, cfg, family = plan["mix"], plan["config"], plan["family"]
     run = build(plan, seed, seconds, traced=trace, smoke=smoke)
     clock = CompileCounter()
     warm_up(run)
@@ -699,8 +724,8 @@ def run_cell(plan: dict, seed: int, seconds: float, trace: bool, *,
     reqs = requests_of(records)
     free_program(run)
 
-    ref_cfg = reference_config(cfg, smoke)
-    gaps = compare_logits(sample_requests(reqs, seed), ref_cfg, seed,
+    ref_cfg = reference_config(cfg, smoke, family)
+    gaps = compare_logits(sample_requests(reqs, seed), ref_cfg, seed, family,
                           control=control)
     limits = plan["limits"]
     # the control's logits are judged in the program's place
@@ -726,7 +751,7 @@ def run_cell(plan: dict, seed: int, seconds: float, trace: bool, *,
             **TRACE_LINES[dev.platform]))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
 
-    dims = counts.Dims.of(ref_cfg)
+    dims = family.Dims.of(ref_cfg)
     ctx = {"setup_s": setup_s, "loop": mix["loop"], "window": window,
            "queries": window["queries"], "requests": reqs,
            "radix_hit_tokens": sum(r["radix_hit_tokens"] for r in records),

@@ -1,5 +1,6 @@
-"""Plain float32 reference of the dense decoder family, and the grammar the
-served answers must follow.  Imports nothing of the program under test.
+"""The grammar the served answers must follow, which every family shares,
+and the plain float32 reference of the dense family only (named by
+``bench/families/dense.py``).  Imports nothing of the program under test.
 
 The weights are made from the seed by the same recipe the program states for
 its random weights (one normal draw per leaf, leaves in sorted tree order,

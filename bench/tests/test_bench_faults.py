@@ -18,7 +18,11 @@ CELLS = ["olmo-1b.reviews-batch", "olmo-1b.docs-shared",
 
 def small_plan(cell):
     """The cell at a size a test run holds: fewer and shorter rows."""
-    plan = harness.cell_plan(harness.load_benchmark(), cell)
+    return shrink(harness.cell_plan(harness.load_benchmark(), cell))
+
+
+def shrink(plan):
+    """A cell's plan cut, in place, to fewer and shorter rows."""
     mix = plan["mix"]
     mix["warmup_rows"] = 4
     if mix["loop"] == "closed":

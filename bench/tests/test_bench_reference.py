@@ -8,7 +8,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench import harness, reference
+from bench import counts, harness, reference
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 # Yi-6B (01-ai/Yi-6B config.json) at 8 of its 32 layers: no cell serves it
@@ -62,7 +62,8 @@ def served_logits(arch, layout, seed):
 @pytest.mark.parametrize("arch", ["olmo-1b", "yi-6b"])
 @pytest.mark.parametrize("layout", ["dense", "paged"])
 def test_engine_logits_match_reference(arch, layout):
-    cfg = harness.reference_config(config(arch), smoke=True)
+    cfg = harness.reference_config(config(arch), smoke=True,
+                                   family=harness.load_family(config(arch)))
     seed = 11
     eng, served = served_logits(arch, layout, seed)
     if layout == "paged":
@@ -86,7 +87,8 @@ def test_reference_weights_are_the_programs():
     import repro.configs as C
     from repro.models import model as MDL
     for arch in ("olmo-1b", "yi-6b"):
-        cfg = harness.reference_config(config(arch), smoke=True)
+        cfg = harness.reference_config(
+            config(arch), smoke=True, family=harness.load_family(config(arch)))
         pc = C.get_smoke_config(arch).replace(vocab_size=259)
         prog = MDL.init_params(pc, jax.random.PRNGKey(3))
         mine = reference.make_weights(cfg, 3)
@@ -116,3 +118,30 @@ def test_grammar_choices():
                              '[{"ok": true}]') is None
     assert reference.widest_gap(np.array([[0.0, 2.0, 1.0]]), np.array([2]),
                                 [(1, 2)]) == 1.0
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "yi-6b"])
+def test_dense_family_is_the_reference(arch):
+    """The dense family the harness reaches through a configuration's
+    ``family`` key gives the same weights, logits and counts, bit for bit,
+    as ``bench/reference.py`` and ``bench/counts.py`` called directly."""
+    if arch == "olmo-1b":
+        plan = harness.cell_plan(harness.load_benchmark(),
+                                 "olmo-1b.reviews-batch")
+        family = plan["family"]
+        assert family.Dims.of(plan["config"]) == \
+            counts.Dims.of(config(arch))
+    else:
+        family = harness.load_family(config(arch))
+    assert family.KEYS == counts.KEYS
+    cfg = harness.reference_config(config(arch), smoke=True, family=family)
+    mine, ref = family.make_weights(cfg, 7), reference.make_weights(cfg, 7)
+    assert sorted(mine) == sorted(ref)
+    for k in ref:
+        np.testing.assert_array_equal(np.asarray(mine[k]), np.asarray(ref[k]))
+    tokens = [reference.BOS] + list(b"a dense family reads these bytes")
+    at = [3, 17, len(tokens) - 1]
+    for fp8 in (False, True):
+        np.testing.assert_array_equal(
+            family.logits_at(mine, cfg, tokens, at, fp8=fp8),
+            reference.logits_at(ref, cfg, tokens, at, fp8=fp8))
