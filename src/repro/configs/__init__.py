@@ -1,8 +1,13 @@
-"""Architecture registry: ``--arch <id>`` resolves through here."""
+"""Architecture registry: ``--arch <id>`` resolves through here.
+
+An id may carry a depth, ``<arch>@<layers>``: the first ``layers`` layers
+of the architecture, its layer pattern kept (``deepseek-v2-lite@7`` is its
+leading dense layer and six expert layers).  The smoke variant of such an
+id is the first ``layers`` layers of the arch's smoke variant."""
 from __future__ import annotations
 
 import importlib
-from typing import Dict, List
+from typing import Dict, List, Optional, Tuple
 
 from repro.models.config import (SHAPES, SHAPES_BY_NAME, ModelConfig,
                                  ShapeSpec, shape_applicable)
@@ -20,21 +25,49 @@ ARCH_IDS: List[str] = [
     "paligemma-3b",
 ]
 
-_MODULES: Dict[str, str] = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}
+# served on the chip through `jax:` but outside the dry-run matrix above
+# (its latent attention has no mesh sharding rules)
+SERVED_IDS: List[str] = ["deepseek-v2-lite"]
+
+_MODULES: Dict[str, str] = {a: a.replace("-", "_").replace(".", "_")
+                            for a in ARCH_IDS + SERVED_IDS}
+
+
+def _split(arch_id: str) -> Tuple[str, Optional[int]]:
+    arch, sep, depth = arch_id.partition("@")
+    if arch not in _MODULES:
+        raise KeyError(f"unknown arch {arch!r}; known: "
+                       f"{ARCH_IDS + SERVED_IDS}")
+    if not sep:
+        return arch, None
+    full = importlib.import_module(f"repro.configs.{_MODULES[arch]}").CONFIG
+    if not depth.isdigit() or not \
+            full.first_dense_layers < int(depth) <= full.num_layers:
+        raise KeyError(f"unknown depth {depth!r} of {arch!r}: a whole number "
+                       f"of layers from {full.first_dense_layers + 1} to "
+                       f"{full.num_layers}")
+    return arch, int(depth)
+
+
+def _cut(cfg: ModelConfig, depth: Optional[int]) -> ModelConfig:
+    return cfg if depth is None else cfg.replace(
+        num_layers=min(depth, cfg.num_layers))
 
 
 def _module(arch_id: str):
-    if arch_id not in _MODULES:
-        raise KeyError(f"unknown arch {arch_id!r}; known: {ARCH_IDS}")
-    return importlib.import_module(f"repro.configs.{_MODULES[arch_id]}")
+    return importlib.import_module(
+        f"repro.configs.{_MODULES[_split(arch_id)[0]]}")
 
 
 def get_config(arch_id: str) -> ModelConfig:
-    return _module(arch_id).CONFIG
+    arch, depth = _split(arch_id)
+    cfg = _cut(_module(arch).CONFIG, depth)
+    return cfg if depth is None else cfg.replace(name=arch_id)
 
 
 def get_smoke_config(arch_id: str) -> ModelConfig:
-    return _module(arch_id).smoke_config()
+    arch, depth = _split(arch_id)
+    return _cut(_module(arch).smoke_config(), depth)
 
 
 def input_specs(arch_id: str, shape_name: str):

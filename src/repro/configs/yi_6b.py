@@ -6,7 +6,7 @@ CONFIG = ModelConfig(
     num_layers=32, d_model=4096, vocab_size=64000,
     num_heads=32, num_kv_heads=4, head_dim=128,
     d_ff=11008,
-    rope_theta=5e6, norm_type="rmsnorm", mlp_act="silu",
+    rope_theta=5e6, norm_type="rmsnorm", norm_eps=1e-5, mlp_act="silu",
 )
 
 def smoke_config() -> ModelConfig:

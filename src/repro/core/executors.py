@@ -29,6 +29,11 @@ import numpy as np
 
 from repro.serving import tokenizer as TOK
 
+#: engine counters of the expert steps (GenStats, CallResult, PredictStats
+#: and ExecStats carry them under these names)
+EXPERT_COUNTERS = ("expert_loads_decode", "expert_slots_decode",
+                   "expert_loads_prefill", "expert_slots_prefill")
+
 
 @dataclasses.dataclass
 class CallResult:
@@ -46,6 +51,10 @@ class CallResult:
     decode_steps: int = 0         # decode ticks the engine ran
     decode_rows: int = 0          # live rows summed over those ticks
     decode_slots: int = 0         # decode-batch width summed over them
+    expert_loads_decode: int = 0   # distinct experts the paged decode
+    expert_slots_decode: int = 0   # steps touched, of expert layers x
+    expert_loads_prefill: int = 0  # experts x steps; the same for the
+    expert_slots_prefill: int = 0  # paged prefill steps
     # per-answer confidence scores, one per returned row, aligned with the
     # parsed objects.  Backends with calibrated scores (tabular classifiers,
     # oracles carrying a "__confidence__" field) populate them; text-only
@@ -160,7 +169,8 @@ class JaxExecutor(Predictor):
                           radix_hit_tokens=s.radix_hit_tokens,
                           decode_steps=s.decode_steps,
                           decode_rows=s.decode_rows,
-                          decode_slots=s.decode_slots)
+                          decode_slots=s.decode_slots,
+                          **{f: getattr(s, f) for f in EXPERT_COUNTERS})
 
     def complete_many(self, prompts, schema, num_rows_list, *,
                       shared_prefix="", rows_list=None, instruction=""):
@@ -241,6 +251,8 @@ class JaxExecutor(Predictor):
         out[0].decode_steps = bs.decode_steps - before.decode_steps
         out[0].decode_rows = bs.decode_rows - before.decode_rows
         out[0].decode_slots = bs.decode_slots - before.decode_slots
+        for f in EXPERT_COUNTERS:
+            setattr(out[0], f, getattr(bs, f) - getattr(before, f))
         return out
 
 

@@ -37,7 +37,7 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.core.cancel import QueryCancelled
-from repro.core.executors import CallResult, Predictor
+from repro.core.executors import EXPERT_COUNTERS, CallResult, Predictor
 from repro.core.faults import DeadlineExceeded, TransientError
 from repro.core.service import (DispatchGroup, InferenceHandle,
                                 InferenceRequest, InferenceService, makespan)
@@ -103,6 +103,10 @@ class PredictStats:
     decode_steps: int = 0          # decode ticks the engine ran
     decode_rows: int = 0           # live rows summed over those ticks
     decode_slots: int = 0          # decode-batch width summed over them
+    expert_loads_decode: int = 0   # distinct experts the paged decode
+    expert_slots_decode: int = 0   # steps touched, of expert layers x
+    expert_loads_prefill: int = 0  # experts x steps; the same for the
+    expert_slots_prefill: int = 0  # paged prefill steps
     # cascade accounting (CascadePredictor backend; zero for direct routes)
     proxy_calls: int = 0           # proxy-stage prompts scored
     escalated_calls: int = 0       # expensive-stage calls actually made
@@ -709,6 +713,8 @@ class PredictOperator:
         self.stats.decode_steps += res.decode_steps
         self.stats.decode_rows += res.decode_rows
         self.stats.decode_slots += res.decode_slots
+        for f in EXPERT_COUNTERS:
+            setattr(self.stats, f, getattr(self.stats, f) + getattr(res, f))
         self.stats.proxy_calls += res.proxy_calls
         self.stats.escalated_calls += res.escalated_calls
         self.stats.cascade_rows += res.cascade_rows

@@ -246,26 +246,6 @@ def cache_pspecs(cfg: ModelConfig, mesh: Mesh, cache_specs: Dict[str, Any],
 
 
 # --------------------------- activation constraints ----------------------------
-def moe_constraint_fns(cfg: ModelConfig, mesh: Mesh, shardable_groups: bool):
-    """dispatch/combine sharding-constraint hooks for the MoE block."""
-    da = data_axes(mesh)
-    gspec = da if shardable_groups else None
-    if cfg.expert_sharding == "ep":
-        disp = P(gspec, "model", None, None)     # (G, E, C, M) → EP all-to-all
-    else:
-        disp = P(gspec, None, None, None)        # stay data-local (TP MoE)
-
-    def dispatch_cs(x):
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, disp))
-
-    def combine_cs(x):
-        # return path: bring experts back token-local before the gather
-        back = P(gspec, None, None, None)
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, back))
-
-    return dispatch_cs, combine_cs
-
-
 def logits_constraint(cfg: ModelConfig, mesh: Mesh, batch_shardable: bool):
     da = data_axes(mesh)
     spec = P(da if batch_shardable else None, None, "model")

@@ -77,26 +77,18 @@ def make_train_step(cfg: ModelConfig, mesh: Optional[Mesh], shape: ShapeSpec,
         unroll_layers = True
         num_micro = 1
     batch_specs = CC.train_batch_specs(cfg, shape.global_batch, shape.seq_len)
-    data_shards = MS.axis_size(mesh, MS.data_axes(mesh)) if mesh else 1
-    micro_tokens = (shape.global_batch // num_micro) * shape.seq_len
-    num_groups = MOE.pick_num_groups(micro_tokens, data_shards) \
-        if cfg.has_moe else 1
-
     if mesh is not None:
         da = MS.data_axes(mesh)
-        dispatch_cs, combine_cs = MS.moe_constraint_fns(cfg, mesh, True)
         logits_cs = MS.logits_constraint(cfg, mesh, True)
         micro_cs = lambda t: jax.tree.map(
             lambda x: jax.lax.with_sharding_constraint(
                 x, NamedSharding(mesh, P(None, da, *([None] * (x.ndim - 2))))), t)
     else:
-        dispatch_cs = combine_cs = logits_cs = MOE.Identity
-        micro_cs = MOE.Identity
+        logits_cs = micro_cs = MOE.Identity
 
     def loss_fn(params, mb):
         logits, _ = MDL.forward(cfg, params, mb, mode="train", remat=remat,
-                                num_groups=num_groups, dispatch_cs=dispatch_cs,
-                                combine_cs=combine_cs, logits_cs=logits_cs,
+                                logits_cs=logits_cs,
                                 attn_fn=attn_fn, scan_fn=scan_fn,
                                 unroll_layers=unroll_layers,
                                 remat_policy=remat_policy)
@@ -161,26 +153,16 @@ def make_prefill_step(cfg: ModelConfig, mesh: Optional[Mesh], shape: ShapeSpec,
         attn_fn = functools.partial(LY.flash_attention, banded=True)
     batch_specs = CC.prefill_batch_specs(cfg, shape.global_batch, shape.seq_len)
     cache_len = cache_len or shape.seq_len
-    data_shards = MS.axis_size(mesh, MS.data_axes(mesh)) if mesh else 1
-    tokens = shape.global_batch * shape.seq_len
-    num_groups = MOE.pick_num_groups(tokens, data_shards) if cfg.has_moe else 1
-
     residual_cs = kv_cs = MOE.Identity
-    if mesh is not None:
-        dispatch_cs, combine_cs = MS.moe_constraint_fns(cfg, mesh, True)
-        if seq_parallel:
-            residual_cs, kv_cs = MS.seq_parallel_hooks(mesh)
-    else:
-        dispatch_cs = combine_cs = MOE.Identity
+    if mesh is not None and seq_parallel:
+        residual_cs, kv_cs = MS.seq_parallel_hooks(mesh)
 
     def prefill_step(params, batch):
         cache = MDL.init_cache(cfg, shape.global_batch, cache_len) \
             if (emit_cache and cfg.supports_decode) else None
         logits, new_cache = MDL.forward(
             cfg, params, batch, mode=("prefill" if cache is not None else "train"),
-            cache=cache, remat=False, num_groups=num_groups,
-            dispatch_cs=dispatch_cs, combine_cs=combine_cs,
-            last_only=cfg.supports_decode,
+            cache=cache, remat=False, last_only=cfg.supports_decode,
             attn_fn=attn_fn, scan_fn=scan_fn, unroll_layers=unroll_layers,
             residual_cs=residual_cs, kv_cs=kv_cs)
         return logits[:, -1], new_cache
@@ -219,20 +201,11 @@ def make_decode_step(cfg: ModelConfig, mesh: Optional[Mesh], shape: ShapeSpec,
     batch_specs = CC.decode_batch_specs(cfg, shape.global_batch)
     cache_specs = MDL.cache_specs(cfg, shape.global_batch, shape.seq_len,
                                   include_row_idx=per_row_write)
-    data_shards = MS.axis_size(mesh, MS.data_axes(mesh)) if mesh else 1
-    num_groups = MOE.pick_num_groups(shape.global_batch, data_shards) \
-        if cfg.has_moe else 1
-
-    if mesh is not None:
-        dispatch_cs, combine_cs = MS.moe_constraint_fns(cfg, mesh, True)
-    else:
-        dispatch_cs = combine_cs = MOE.Identity
 
     def decode_step(params, batch, cache):
         logits, new_cache = MDL.forward(
             cfg, params, batch, mode="decode", cache=cache, remat=False,
-            num_groups=num_groups, dispatch_cs=dispatch_cs,
-            combine_cs=combine_cs, unroll_layers=calibrate)
+            unroll_layers=calibrate)
         return logits, new_cache
 
     if mesh is None:

@@ -41,13 +41,32 @@ class ModelConfig:
     sliding_window: int = 0          # 0 → full attention
     rope_theta: float = 10_000.0
     causal: bool = True
+    # Latent attention (MLA, DeepSeek-V2; kv_lora_rank == 0 → off): the
+    # cache holds a kv_lora_rank latent plus one qk_rope_head_dim rotary key
+    # per token; head_dim is then qk_nope_head_dim + qk_rope_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (yarn_factor == 0 → plain rope)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # MLP
     d_ff: int = 0                    # per-expert width for MoE
     mlp_act: str = "silu"            # silu (SwiGLU) | gelu (plain 2-layer)
     # MoE
     num_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    num_shared_experts: int = 0      # always-on experts, one SwiGLU of
+                                     # num_shared_experts * d_ff
+    norm_topk_prob: bool = True      # softmax over the top-k logits; False:
+                                     # top-k of the softmax over all experts
+    first_dense_layers: int = 0      # leading layers with a dense MLP
+    dense_d_ff: int = 0              # ... of this width
     # SSM (mamba-1)
     ssm_state: int = 0
     ssm_conv: int = 4
@@ -55,6 +74,7 @@ class ModelConfig:
     dt_rank: int = 0                 # 0 → d_model // 16
     # Norm
     norm_type: str = "rmsnorm"       # rmsnorm | layernorm | nonparam_ln
+    norm_eps: Optional[float] = None  # None → 1e-6 rmsnorm, 1e-5 layernorm
     # Embedding
     tie_embeddings: bool = False
     scale_embed: bool = False        # gemma-style sqrt(d_model) scaling
@@ -65,6 +85,25 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
 
     # -- derived -------------------------------------------------------------
+    @property
+    def eps(self) -> float:
+        if self.norm_eps is not None:
+            return self.norm_eps
+        return 1e-6 if self.norm_type == "rmsnorm" else 1e-5
+
+    @property
+    def has_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Values an MLA cache holds per token and layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_dense_layers if self.has_moe else 0
+
     @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
@@ -144,6 +183,11 @@ class ModelConfig:
     def _attn_params(self) -> int:
         if not self.has_attention:
             return 0
+        if self.has_mla:
+            h, m, r = self.num_heads, self.d_model, self.kv_lora_rank
+            return (m * h * self.head_dim + m * self.latent_dim + r
+                    + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * m)
         h, kv, hd, m = self.padded_heads, self.num_kv_heads, self.head_dim, self.d_model
         p = m * h * hd + 2 * m * kv * hd + h * hd * m
         if self.qkv_bias:
@@ -161,7 +205,8 @@ class ModelConfig:
         if not self.has_moe:
             return 0
         per_expert = 3 * self.d_model * self.d_ff
-        return self.num_experts * per_expert + self.d_model * self.num_experts
+        return (self.num_experts + self.num_shared_experts) * per_expert \
+            + self.d_model * self.num_experts
 
     def _ssm_params(self) -> int:
         if not self.has_ssm:
@@ -196,20 +241,24 @@ class ModelConfig:
         per_layer = (self._attn_params() + self._mlp_params()
                      + self._moe_params() + self._ssm_params()
                      + self._norm_params()) - heads_saved
+        # leading dense layers: a dense MLP in place of the experts
+        dense_swap = self.first_dense_layers * (
+            3 * self.d_model * self.dense_d_ff - self._moe_params())
         embed = 0 if self.family == ENCODER else vocab * self.d_model
         head = 0 if self.tie_embeddings else vocab * self.d_model
         final_norm = 0 if self.norm_type == "nonparam_ln" else (
             self.d_model * (2 if self.norm_type == "layernorm" else 1))
-        return self.num_layers * per_layer + embed + head + final_norm
+        return self.num_layers * per_layer + dense_swap + embed + head \
+            + final_norm
 
     def active_param_count(self) -> int:
         """Activated parameters per token (MoE: top_k of num_experts)."""
         if not self.has_moe:
             return self.param_count()
         full = self.param_count()
-        moe_all = self.num_layers * self.num_experts * 3 * self.d_model * self.d_ff
-        moe_active = self.num_layers * self.top_k * 3 * self.d_model * self.d_ff
-        return full - moe_all + moe_active
+        per_expert = 3 * self.d_model * self.d_ff
+        return full - self.num_moe_layers * (self.num_experts - self.top_k) \
+            * per_expert
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

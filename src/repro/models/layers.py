@@ -21,20 +21,22 @@ NEG_INF = -1e30
 
 
 # -- norms ---------------------------------------------------------------------
-def rmsnorm(x: jax.Array, w: Optional[jax.Array]) -> jax.Array:
+def rmsnorm(x: jax.Array, w: Optional[jax.Array], eps: float = 1e-6
+            ) -> jax.Array:
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    y = xf * jax.lax.rsqrt(var + 1e-6)
+    y = xf * jax.lax.rsqrt(var + eps)
     if w is not None:
         y = y * w.astype(jnp.float32)
     return y.astype(x.dtype)
 
 
-def layernorm(x: jax.Array, w: Optional[jax.Array], b: Optional[jax.Array]) -> jax.Array:
+def layernorm(x: jax.Array, w: Optional[jax.Array], b: Optional[jax.Array],
+              eps: float = 1e-5) -> jax.Array:
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + 1e-5)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps)
     if w is not None:
         y = y * w.astype(jnp.float32)
     if b is not None:
@@ -42,22 +44,62 @@ def layernorm(x: jax.Array, w: Optional[jax.Array], b: Optional[jax.Array]) -> j
     return y.astype(x.dtype)
 
 
-def apply_norm(norm_type: str, x: jax.Array, params: Optional[dict]) -> jax.Array:
+def apply_norm(norm_type: str, x: jax.Array, params: Optional[dict],
+               eps: float) -> jax.Array:
     if norm_type == "rmsnorm":
-        return rmsnorm(x, params["scale"] if params else None)
+        return rmsnorm(x, params["scale"] if params else None, eps)
     if norm_type == "layernorm":
-        return layernorm(x, params["scale"], params["bias"])
+        return layernorm(x, params["scale"], params["bias"], eps)
     if norm_type == "nonparam_ln":
-        return layernorm(x, None, None)
+        return layernorm(x, None, None, eps)
     raise ValueError(norm_type)
 
 
 # -- rotary embeddings ----------------------------------------------------------
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, D) with D even; positions: (B, S) absolute positions."""
+def _yarn_dim(rotations: float, dim: int, base: float, max_pos: int) -> float:
+    """The rotary dimension that turns ``rotations`` times over ``max_pos``."""
+    return dim * math.log(max_pos / (rotations * 2 * math.pi)) \
+        / (2 * math.log(base))
+
+
+def yarn_range(dim: int, base: float, max_pos: int, beta_fast: float,
+               beta_slow: float) -> Tuple[int, int]:
+    """(low, high): frequencies below ``low`` keep their period, those above
+    ``high`` are interpolated; a linear ramp in between (YaRN,
+    arXiv:2309.00071)."""
+    low = math.floor(_yarn_dim(beta_fast, dim, base, max_pos))
+    high = math.ceil(_yarn_dim(beta_slow, dim, base, max_pos))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_inv_freq(dim: int, theta: float, yarn=None) -> jax.Array:
+    """(dim // 2,) inverse frequencies; ``yarn`` (factor, original max
+    positions, beta_fast, beta_slow) blends interpolated and original
+    frequencies along YaRN's ramp."""
+    half = dim // 2
+    extra = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32)
+                    / half)
+    if not yarn:
+        return extra
+    factor, max_pos, beta_fast, beta_slow = yarn
+    low, high = yarn_range(dim, theta, max_pos, beta_fast, beta_slow)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq: Optional[jax.Array] = None) -> jax.Array:
+    """x: (B, S, H, D) with D even; positions: (B, S) absolute positions.
+    Rotates the halves (i, i + D/2) with frequency i; ``inv_freq``
+    overrides the plain ``theta`` frequencies."""
     d = x.shape[-1]
     half = d // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    freqs = rope_inv_freq(d, theta) if inv_freq is None else inv_freq
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
@@ -304,21 +346,26 @@ def reference_attention(q, k, v, q_positions, kv_positions,
 
 
 # -- decode attention (single query token vs. long KV cache) --------------------
-def decode_attention(q, k_cache, v_cache, cache_positions, q_position):
-    """q (B, H, D); caches (B, L, KV, D); cache_positions (B, L) absolute
-    positions of each cache slot (-1 = empty); q_position (B,).
-    Returns (B, H, D). Pure jnp — the Pallas twin lives in kernels/decode_attention.
+def decode_attention(q, k_cache, v_cache, cache_positions, q_position, *,
+                     scale: Optional[float] = None):
+    """q (B, H, D); k_cache (B, L, KV, D); v_cache (B, L, KV, Dv), Dv may be
+    less than D; cache_positions (B, L) absolute positions of each cache
+    slot (-1 = empty); q_position (B,); ``scale`` defaults to D^-0.5.
+    Returns (B, H, Dv). Pure jnp — the Pallas twin lives in
+    kernels/decode_attention.
     """
     B, H, D = q.shape
     _, L, KV, _ = k_cache.shape
+    Dv = v_cache.shape[-1]
     G = H // KV
-    qf = q.astype(jnp.float32).reshape(B, KV, G, D) / math.sqrt(D)
+    qf = q.astype(jnp.float32).reshape(B, KV, G, D)
+    qf = qf / math.sqrt(D) if scale is None else qf * scale
     s = jnp.einsum("bkgd,blkd->bkgl", qf, k_cache.astype(jnp.float32))
     valid = (cache_positions >= 0) & (cache_positions <= q_position[:, None])
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgl,blkd->bkgd", p, v_cache.astype(jnp.float32))
-    return o.reshape(B, H, D).astype(q.dtype)
+    return o.reshape(B, H, Dv).astype(q.dtype)
 
 
 # -- paged decode attention (block-table addressed page pool) -------------------
@@ -365,7 +412,8 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, q_position, *,
 
 
 def prefix_suffix_attention(q, k_prefix, v_prefix, k_suf, v_suf,
-                            positions, prefix_len):
+                            positions, prefix_len, *,
+                            scale: Optional[float] = None):
     """Shared-prefix prefill attention WITHOUT replicating the prefix KV.
 
     q (B, S, H, D) suffix queries; k_prefix/v_prefix (Lp, KV, D) — ONE copy
@@ -376,12 +424,15 @@ def prefix_suffix_attention(q, k_prefix, v_prefix, k_suf, v_suf,
     every suffix query (their positions precede all suffix positions);
     the suffix part is causal. The two score blocks are merged with a
     joint streamed-softmax so the result equals one softmax over
-    [prefix ++ suffix].
+    [prefix ++ suffix]. Values may be narrower than keys; ``scale``
+    defaults to D^-0.5.
     """
     B, S, H, D = q.shape
     KV = k_suf.shape[2]
+    Dv = v_suf.shape[-1]
     G = H // KV
-    qf = q.astype(jnp.float32).reshape(B, S, KV, G, D) / math.sqrt(D)
+    qf = q.astype(jnp.float32).reshape(B, S, KV, G, D)
+    qf = qf / math.sqrt(D) if scale is None else qf * scale
 
     ss = jnp.einsum("bskgd,btkd->bkgst", qf, k_suf.astype(jnp.float32))
     ok_s = (positions[:, None, :] >= 0) & \
@@ -406,7 +457,7 @@ def prefix_suffix_attention(q, k_prefix, v_prefix, k_suf, v_suf,
         denom = jnp.maximum(psx.sum(-1), 1e-30)
         o = jnp.einsum("bkgst,btkd->bskgd", psx, v_suf.astype(jnp.float32))
     o = o / denom.transpose(0, 3, 1, 2)[..., None]
-    return o.reshape(B, S, H, D).astype(q.dtype)
+    return o.reshape(B, S, H, Dv).astype(q.dtype)
 
 
 # -- MLPs ------------------------------------------------------------------------

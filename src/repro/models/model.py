@@ -40,8 +40,10 @@ def _dt(name: str):
 
 
 # =============================== parameters ===================================
-def _layer_shapes(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
-    """Per-layer leaf name → (shape-without-L, dtype)."""
+def _layer_shapes(cfg: ModelConfig, dense: bool = False
+                  ) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+    """Per-layer leaf name → (shape-without-L, dtype). ``dense``: one of the
+    leading layers whose MLP is dense (``first_dense_layers``)."""
     m, pd = cfg.d_model, _dt(cfg.param_dtype)
     h, kv, hd = cfg.padded_heads, cfg.num_kv_heads, cfg.head_dim
     out: Dict[str, Tuple[Tuple[int, ...], Any]] = {}
@@ -54,7 +56,16 @@ def _layer_shapes(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
             out[f"{prefix}.bias"] = ((m,), pd)
         # nonparam_ln: no params
 
-    if cfg.has_attention:
+    if cfg.has_mla:
+        norm("ln_attn")
+        r = cfg.kv_lora_rank
+        out["attn.wq"] = ((m, h, hd), pd)
+        out["attn.wkv_a"] = ((m, cfg.latent_dim), pd)   # [latent, rope key]
+        out["attn.kv_norm.scale"] = ((r,), pd)
+        out["attn.wkv_b"] = ((r, h, cfg.qk_nope_head_dim + cfg.v_head_dim),
+                             pd)                        # [k_nope, v]
+        out["attn.wo"] = ((h, cfg.v_head_dim, m), pd)
+    elif cfg.has_attention:
         norm("ln_attn")
         # 3D layout keeps head vs head_dim sharding choices expressible
         out["attn.wq"] = ((m, h, hd), pd)
@@ -79,24 +90,30 @@ def _layer_shapes(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
         out["ssm.A_log"] = ((di, n), jnp.float32)
         out["ssm.D"] = ((di,), jnp.float32)
         out["ssm.out_proj"] = ((di, m), pd)
-    if cfg.has_mlp:
+    if cfg.has_mlp or dense:
         norm("ln_mlp")
+        f = cfg.dense_d_ff if dense else cfg.d_ff
         if cfg.mlp_act == "silu":
-            out["mlp.w_gate"] = ((m, cfg.d_ff), pd)
-            out["mlp.w_up"] = ((m, cfg.d_ff), pd)
-            out["mlp.w_down"] = ((cfg.d_ff, m), pd)
+            out["mlp.w_gate"] = ((m, f), pd)
+            out["mlp.w_up"] = ((m, f), pd)
+            out["mlp.w_down"] = ((f, m), pd)
         else:
-            out["mlp.w_in"] = ((m, cfg.d_ff), pd)
-            out["mlp.b_in"] = ((cfg.d_ff,), pd)
-            out["mlp.w_out"] = ((cfg.d_ff, m), pd)
+            out["mlp.w_in"] = ((m, f), pd)
+            out["mlp.b_in"] = ((f,), pd)
+            out["mlp.w_out"] = ((f, m), pd)
             out["mlp.b_out"] = ((m,), pd)
-    if cfg.has_moe:
+    elif cfg.has_moe:
         norm("ln_mlp")
         e, f = cfg.num_experts, cfg.d_ff
         out["moe.router"] = ((m, e), pd)
         out["moe.w_gate"] = ((e, m, f), pd)
         out["moe.w_up"] = ((e, m, f), pd)
         out["moe.w_down"] = ((e, f, m), pd)
+        if cfg.num_shared_experts:
+            fs = cfg.num_shared_experts * f
+            out["moe.shared_gate"] = ((m, fs), pd)
+            out["moe.shared_up"] = ((m, fs), pd)
+            out["moe.shared_down"] = ((fs, m), pd)
     return out
 
 
@@ -106,10 +123,11 @@ def _layer_shapes(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
 _COMPUTE_DTYPE_LEAVES = frozenset({
     "embed", "lm_head",
     "attn.wq", "attn.wk", "attn.wv", "attn.wo", "attn.bq", "attn.bk",
-    "attn.bv",
+    "attn.bv", "attn.wkv_a", "attn.wkv_b",
     "mlp.w_gate", "mlp.w_up", "mlp.w_down",
     "mlp.w_in", "mlp.b_in", "mlp.w_out", "mlp.b_out",
     "moe.w_gate", "moe.w_up", "moe.w_down",
+    "moe.shared_gate", "moe.shared_up", "moe.shared_down",
     "ssm.in_x", "ssm.in_z", "ssm.conv_w", "ssm.conv_b", "ssm.x_proj",
     "ssm.dt_proj", "ssm.out_proj",
 })
@@ -144,9 +162,15 @@ def tree_bytes(tree: PyTree) -> int:
 def param_specs(cfg: ModelConfig) -> PyTree:
     """ShapeDtypeStructs for the full parameter tree (stacked layers)."""
     m, vp, pd = cfg.d_model, cfg.padded_vocab, _dt(cfg.param_dtype)
+    fd = cfg.first_dense_layers
     tree: Dict[str, Any] = {"layers": {}}
     for name, (shape, dt) in _layer_shapes(cfg).items():
-        tree["layers"][name] = jax.ShapeDtypeStruct((cfg.num_layers,) + shape, dt)
+        tree["layers"][name] = jax.ShapeDtypeStruct(
+            (cfg.num_layers - fd,) + shape, dt)
+    if fd:
+        tree["dense_layers"] = {
+            name: jax.ShapeDtypeStruct((fd,) + shape, dt)
+            for name, (shape, dt) in _layer_shapes(cfg, dense=True).items()}
     if cfg.family != ENCODER:
         tree["embed"] = jax.ShapeDtypeStruct((vp, m), pd)
     if not cfg.tie_embeddings:
@@ -159,11 +183,25 @@ def param_specs(cfg: ModelConfig) -> PyTree:
     return tree
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _draw_layers(key, shape, std: float, dtype):
+    """A stacked leaf drawn one layer at a time, layer i from split i of
+    `key`, in one program: a single layer's float32 draw is live at a time
+    (drawn eagerly, every layer's would be queued at once)."""
+    return jax.lax.map(
+        lambda k: (jax.random.normal(k, shape[1:], jnp.float32) * std
+                   ).astype(dtype),
+        jax.random.split(key, shape[0]))
+
+
 def init_params(cfg: ModelConfig, key: jax.Array,
                 specs: Optional[PyTree] = None) -> PyTree:
-    """Seeded weights. Each leaf is drawn in float32 and cast to its spec's
-    dtype; `specs` (default `param_specs(cfg)`) may be its serving tree, so
-    that no float32 copy of a compute-dtype leaf outlives its own draw."""
+    """Seeded weights. Each leaf is drawn in float32 from its own key and
+    cast to its spec's dtype; `specs` (default `param_specs(cfg)`) may be
+    its serving tree, so that no float32 copy of a compute-dtype leaf
+    outlives its own draw.  A stacked `moe.*` leaf is drawn one layer at a
+    time, each layer from its own split of the leaf's key, so that no
+    float32 copy of all its layers' experts is ever held."""
     specs = param_specs(cfg) if specs is None else specs
     flat_paths, treedef = jax.tree_util.tree_flatten_with_path(specs)
     keys = jax.random.split(key, len(flat_paths))
@@ -183,12 +221,16 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         else:
             if "attn.w" in p:
                 start = 1 if stacked else 0
-                fan_in = (s.shape[start] if p.endswith(("wq']", "wk']", "wv']"))
+                fan_in = (s.shape[start] if p.endswith(
+                    ("wq']", "wk']", "wv']", "wkv_a']", "wkv_b']"))
                           else s.shape[start] * s.shape[start + 1])
             else:
                 fan_in = s.shape[-2]
             std = 1.0 / math.sqrt(max(1, fan_in))
-            v = jax.random.normal(k, s.shape, jnp.float32) * std
+            if stacked and "moe." in p:
+                v = _draw_layers(k, s.shape, std, s.dtype)
+            else:
+                v = jax.random.normal(k, s.shape, jnp.float32) * std
         vals.append(v.astype(s.dtype))
     return treedef.unflatten(vals)
 
@@ -250,10 +292,18 @@ def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int,
     reshapes to the Pallas kernel's (KV·P, ps, Dp) view for free, so the
     decode step pays no per-step transpose.  With `quant`, int8 shadow
     pools plus per-(layer, kv-head, page) scales are added for
-    quantize-on-commit of frozen shared-prefix pages."""
+    quantize-on-commit of frozen shared-prefix pages.
+
+    Latent attention keeps one pool "ckv" of (layers, 1, P, ps, Dp): each
+    token's normalised latent and its rotary key, kv_lora_rank +
+    qk_rope_head_dim values padded to Dp lanes, and no V pool."""
     ln, cd = cfg.num_layers, _dt(cfg.compute_dtype)
     out: Dict[str, Any] = {"idx": jax.ShapeDtypeStruct((), jnp.int32)}
-    if cfg.has_attention:
+    if cfg.has_mla:
+        assert not quant, "latent pools are not quantized"
+        out["ckv"] = jax.ShapeDtypeStruct(
+            (ln, 1, num_pages, page_size, padded_head_dim(cfg.latent_dim)), cd)
+    elif cfg.has_attention:
         kv, hd = cfg.num_kv_heads, cfg.head_dim
         dp = padded_head_dim(hd)
         out["k"] = jax.ShapeDtypeStruct((ln, kv, num_pages, page_size, dp), cd)
@@ -298,6 +348,24 @@ def _fold_write(x: jax.Array, dp: int) -> jax.Array:
     if pad:
         x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
     return x
+
+
+def _page_slots(bt: jax.Array, positions: jax.Array, num_pages: int,
+                page_size: int):
+    """(page, offset) of each position through the block table bt (B, NB);
+    positions (B,) or (B, S).  A slot with no page (pad position, entry -1
+    or past the table, i.e. past max_len) gets page `num_pages`, out of
+    bounds, so that its write is dropped: the sequence keeps decoding
+    against a frozen cache.  The dense layout ring-wraps instead; both are
+    out of contract past max_len."""
+    nb = bt.shape[1]
+    blk = jnp.clip(positions, 0, None) // page_size
+    pos2 = blk if blk.ndim == 2 else blk[:, None]
+    entry = jnp.take_along_axis(bt, jnp.clip(pos2, 0, nb - 1), axis=1)
+    entry = entry.reshape(blk.shape)
+    ok = (positions >= 0) & (blk < nb) & (entry >= 0)
+    return (jnp.where(ok, entry, num_pages),
+            jnp.clip(positions, 0, None) % page_size)
 
 
 def _dequant_pages(qd: Dict[str, jax.Array], safe_pages: jax.Array,
@@ -349,18 +417,8 @@ def _attention(cfg: ModelConfig, x, lp, positions, mode, ck, cv, slot_pos, idx,
     if paged is not None and mode == "decode":
         bt = paged["block_tables"]
         KV_, P_, ps_, Dp_ = ck.shape
-        NB_ = bt.shape[1]
         pos = positions[:, 0]                                     # (B,)
-        blk = jnp.clip(pos, 0, None) // ps_
-        entry = jnp.take_along_axis(
-            bt, jnp.clip(blk, 0, NB_ - 1)[:, None], axis=1)[:, 0]
-        # beyond table capacity (pos >= NB_·ps_, i.e. past max_len) writes
-        # are dropped — the sequence keeps decoding against a frozen cache.
-        # The dense layout ring-wraps instead; both are out of contract
-        # past max_len and the layouts' byte-equality only holds within it.
-        ok = (pos >= 0) & (blk < NB_) & (entry >= 0)
-        page = jnp.where(ok, entry, P_)        # P_ is out of bounds → drop
-        off = jnp.clip(pos, 0, None) % ps_
+        page, off = _page_slots(bt, pos, P_, ps_)
         # per-axis indexing keeps the P_ out-of-bounds drop trick safe: the
         # page axis is indexed on its own, so an invalid id can never fold
         # into a neighbouring kv-head's page 0
@@ -380,7 +438,6 @@ def _attention(cfg: ModelConfig, x, lp, positions, mode, ck, cv, slot_pos, idx,
         pt = paged.get("prefix_table")
         plen = paged.get("prefix_len", jnp.int32(0))
         KV_, P_, ps_, Dp_ = ck.shape
-        NB_ = bt.shape[1]
         if pt is not None and pt.shape[0]:
             safe_pt = jnp.clip(pt, 0, P_ - 1)
             kp = ck[:, safe_pt]                       # (KV, npre, ps, Dp)
@@ -393,11 +450,7 @@ def _attention(cfg: ModelConfig, x, lp, positions, mode, ck, cv, slot_pos, idx,
             kp = jnp.zeros((0, KV_, hd), ck.dtype)
             vp = jnp.zeros((0, KV_, hd), cv.dtype)
         o = L.prefix_suffix_attention(q, kp, vp, k, v, positions, plen)
-        blk = jnp.clip(positions, 0, None) // ps_                 # (B, S)
-        entry = jnp.take_along_axis(bt, jnp.clip(blk, 0, NB_ - 1), axis=1)
-        ok = (positions >= 0) & (blk < NB_) & (entry >= 0)
-        page = jnp.where(ok, entry, P_)
-        off = jnp.clip(positions, 0, None) % ps_
+        page, off = _page_slots(bt, positions, P_, ps_)
         new_ck = ck.at[:, page, off].set(
             _fold_write(k, Dp_).astype(ck.dtype), mode="drop")
         new_cv = cv.at[:, page, off].set(
@@ -454,12 +507,141 @@ def _attention(cfg: ModelConfig, x, lp, positions, mode, ck, cv, slot_pos, idx,
     return out, new_ck, new_cv
 
 
+def _rope_setup(cfg: ModelConfig):
+    """(inverse frequencies, factor on the rotated values, softmax scale) of
+    latent attention's rotary part: YaRN-scaled when `yarn_factor` is set
+    (DeepSeek-V2's `DeepseekV2YarnRotaryEmbedding`)."""
+    dr = cfg.qk_rope_head_dim
+    scale = cfg.head_dim ** -0.5
+    if not cfg.yarn_factor:
+        return L.rope_inv_freq(dr, cfg.rope_theta), 1.0, scale
+    f = cfg.yarn_factor
+    inv = L.rope_inv_freq(dr, cfg.rope_theta, (
+        f, cfg.yarn_original_max_pos, cfg.yarn_beta_fast, cfg.yarn_beta_slow))
+    ms_all = L.yarn_mscale(f, cfg.yarn_mscale_all_dim) \
+        if cfg.yarn_mscale_all_dim else 1.0
+    return inv, L.yarn_mscale(f, cfg.yarn_mscale) / ms_all, scale * ms_all ** 2
+
+
+def _pair_rope(x, positions, inv_freq, factor: float):
+    """Rotary embedding of adjacent pairs (2i, 2i+1) at frequency i, as
+    DeepSeek-V2 does: the pairs are de-interleaved (evens, then odds) and
+    the halves rotated.  The result stays de-interleaved; queries and keys
+    get the same permutation, so their dot products are those of the
+    pairwise rotation."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    y = L.rope(x, positions, 0.0, inv_freq)
+    return y if factor == 1.0 else (y * factor).astype(y.dtype)
+
+
+def _mla_attention(cfg: ModelConfig, x, lp, positions, mode, pool, layer,
+                   paged):
+    """Latent attention (DeepSeek-V2, arXiv:2405.04434). x (B,S,M).  The
+    cache is the whole latent pool (layers, 1, P, ps, Dp), read and written
+    at `layer`.  Decode scores the absorbed query (q_nope through W_kvb's
+    key half) against the cached [latent, rope key] and maps the latent
+    output through W_kvb's value half; prefill and train up-project the
+    latents to per-head keys and values.  Returns (out (B,S,M), pool)."""
+    cd = _dt(cfg.compute_dtype)
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dl = cfg.qk_nope_head_dim, cfg.latent_dim
+    inv, factor, scale = _rope_setup(cfg)
+    wkv_b = lp["attn.wkv_b"].astype(cd)                  # (r, h, dn + dv)
+    with jax.named_scope("mla"):
+        q = jnp.einsum("bsm,mhd->bshd", x, lp["attn.wq"].astype(cd))
+        q_pe = _pair_rope(q[..., dn:], positions, inv, factor)
+        kva = jnp.einsum("bsm,mr->bsr", x, lp["attn.wkv_a"].astype(cd))
+        c = L.rmsnorm(kva[..., :r], lp["attn.kv_norm.scale"], cfg.eps)
+        k_pe = _pair_rope(kva[:, :, None, r:], positions, inv, factor)
+        lat = jnp.concatenate([c, k_pe[:, :, 0]], axis=-1)   # (B, S, dl)
+
+        def write(pool, page, off, vals):
+            vals = jnp.pad(vals, [(0, 0)] * (vals.ndim - 1)
+                           + [(0, pool.shape[-1] - dl)])
+            return pool.at[layer, 0, page, off].set(vals.astype(pool.dtype),
+                                                    mode="drop")
+
+        if mode == "decode":
+            bt = paged["block_tables"]
+            P_, ps_ = pool.shape[2], pool.shape[3]
+            pos = positions[:, 0]
+            pool = write(pool, *_page_slots(bt, pos, P_, ps_), lat[:, 0])
+            q_lat = jnp.einsum("bhd,rhd->bhr", q[:, 0, :, :dn],
+                               wkv_b[..., :dn],
+                               preferred_element_type=jnp.float32)
+            qf = jnp.concatenate([q_lat, q_pe[:, 0].astype(jnp.float32)],
+                                 axis=-1)                       # (B, h, dl)
+            B, NB = bt.shape
+            pages = pool[layer, 0, jnp.clip(bt, 0, P_ - 1)]  # (B,NB,ps,Dp)
+            pages = pages.reshape(B, NB * ps_, 1, pool.shape[-1])
+            slot = jnp.broadcast_to(jnp.arange(NB * ps_, dtype=jnp.int32),
+                                    (B, NB * ps_))
+            slot = jnp.where(jnp.repeat(bt >= 0, ps_, axis=1), slot, -1)
+            o_lat = L.decode_attention(qf, pages[..., :dl], pages[..., :r],
+                                       slot, pos, scale=scale)
+            o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(cd),
+                           wkv_b[..., dn:])[:, None]
+        else:
+            def expand(c, k_pe):
+                kv = jnp.einsum("...r,rhd->...hd", c, wkv_b)
+                k_pe = jnp.broadcast_to(k_pe[..., None, :],
+                                        kv.shape[:-1] + k_pe.shape[-1:])
+                return (jnp.concatenate([kv[..., :dn], k_pe], axis=-1),
+                        kv[..., dn:])
+
+            k, v = expand(c, k_pe[:, :, 0])
+            qf = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+            pt = None if paged is None else paged.get("prefix_table")
+            plen = jnp.int32(0) if paged is None else \
+                paged.get("prefix_len", jnp.int32(0))
+            if pt is not None and pt.shape[0]:
+                # shared prefix pages read in place, one copy for the batch
+                P_ = pool.shape[2]
+                pre = pool[layer, 0, jnp.clip(pt, 0, P_ - 1)]
+                pre = pre.reshape(-1, pool.shape[-1])
+                kp, vp = expand(pre[:, :r], pre[:, r:dl])
+            else:
+                kp = jnp.zeros((0,) + k.shape[2:], k.dtype)
+                vp = jnp.zeros((0,) + v.shape[2:], v.dtype)
+            o = L.prefix_suffix_attention(qf, kp, vp, k, v, positions, plen,
+                                          scale=scale)
+            if paged is not None:
+                bt = paged["block_tables"]
+                pool = write(pool, *_page_slots(bt, positions, pool.shape[2],
+                                                pool.shape[3]), lat)
+        out = jnp.einsum("bshd,hdm->bsm", o, lp["attn.wo"].astype(cd))
+    return out, pool
+
+
+def _mlp(cfg: ModelConfig, x, lp, valid, dense: bool):
+    """The block's MLP on the normed x (B,S,M): dense SwiGLU / GELU, or the
+    experts.  Returns (y, distinct experts the valid tokens chose, or
+    None)."""
+    B, S, m = x.shape
+    if cfg.has_moe and not dense:
+        moe_p = {k[4:]: v for k, v in lp.items() if k.startswith("moe.")}
+        y, idx = MOE.moe_block(x.reshape(B * S, m), moe_p,
+                               num_experts=cfg.num_experts, top_k=cfg.top_k,
+                               norm_topk_prob=cfg.norm_topk_prob,
+                               compute_dtype=_dt(cfg.compute_dtype))
+        return y.reshape(B, S, m), MOE.experts_touched(
+            idx, cfg.num_experts, valid.reshape(-1))
+    if cfg.mlp_act == "silu":
+        return L.swiglu_mlp(x, lp["mlp.w_gate"].astype(x.dtype),
+                            lp["mlp.w_up"].astype(x.dtype),
+                            lp["mlp.w_down"].astype(x.dtype)), None
+    return L.gelu_mlp(x, lp["mlp.w_in"].astype(x.dtype),
+                      lp["mlp.b_in"].astype(x.dtype),
+                      lp["mlp.w_out"].astype(x.dtype),
+                      lp["mlp.b_out"].astype(x.dtype)), None
+
+
 def _block(cfg: ModelConfig, x, lp, positions, mode, cache_l, *,
-           num_groups=1, dispatch_cs=MOE.Identity, combine_cs=MOE.Identity,
            attn_fn=None, decode_attn_fn=None, scan_fn=None,
            extend_offset: int = 0, kv_cs=MOE.Identity, paged=None):
-    """One residual block. cache_l: per-layer cache slice dict (or {})."""
-    B, S, m = x.shape
+    """One residual block. cache_l: per-layer cache slice dict (or {}).
+    Returns (x, new cache slice, distinct experts chosen or None)."""
+    eps = cfg.eps
     new_cache = dict(cache_l)
     slot_pos = cache_l.get("slot_pos")
     idx = cache_l.get("idx", jnp.int32(0))
@@ -472,7 +654,7 @@ def _block(cfg: ModelConfig, x, lp, positions, mode, cache_l, *,
             "flags": paged["quant_flags"]}}
 
     if cfg.family == HYBRID:
-        xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_attn"))
+        xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_attn"), eps)
         a, nk, nv = _attention(cfg, xin, lp, positions, mode,
                                cache_l.get("k"), cache_l.get("v"), slot_pos, idx,
                                attn_fn, decode_attn_fn, extend_offset,
@@ -488,14 +670,14 @@ def _block(cfg: ModelConfig, x, lp, positions, mode, cache_l, *,
         x = x + 0.5 * (a + s)
         if mode != "train":
             new_cache.update(k=nk, v=nv, conv=new_state.conv, h=new_state.h)
-        xin2 = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_mlp"))
+        xin2 = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_mlp"), eps)
         x = x + L.swiglu_mlp(xin2, lp["mlp.w_gate"].astype(x.dtype),
                              lp["mlp.w_up"].astype(x.dtype),
                              lp["mlp.w_down"].astype(x.dtype))
-        return x, new_cache
+        return x, new_cache, None
 
     if cfg.family == SSM:
-        xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_ssm"))
+        xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_ssm"), eps)
         state = None
         if mode != "train":
             state = M.SSMState(conv=cache_l["conv"], h=cache_l["h"])
@@ -506,10 +688,10 @@ def _block(cfg: ModelConfig, x, lp, positions, mode, cache_l, *,
             state=state, scan_fn=scan_fn or M.selective_scan)
         if mode != "train":
             new_cache.update(conv=new_state.conv, h=new_state.h)
-        return x + s, new_cache
+        return x + s, new_cache, None
 
     # attention families: dense / moe / encoder / vlm
-    xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_attn"))
+    xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_attn"), eps)
     a, nk, nv = _attention(cfg, xin, lp, positions, mode,
                            cache_l.get("k"), cache_l.get("v"), slot_pos, idx,
                            attn_fn, decode_attn_fn, extend_offset,
@@ -517,26 +699,41 @@ def _block(cfg: ModelConfig, x, lp, positions, mode, cache_l, *,
     x = x + a
     if mode != "train" and cfg.has_attention:
         new_cache.update(k=nk, v=nv)
-    xin2 = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_mlp"))
-    if cfg.has_moe:
-        moe_p = {k[4:]: v for k, v in lp.items() if k.startswith("moe.")}
-        y = MOE.moe_block(xin2.reshape(B * S, m), moe_p,
-                          num_experts=cfg.num_experts, top_k=cfg.top_k,
-                          capacity_factor=cfg.capacity_factor,
-                          num_groups=num_groups, dispatch_cs=dispatch_cs,
-                          combine_cs=combine_cs,
-                          compute_dtype=_dt(cfg.compute_dtype))
-        x = x + y.reshape(B, S, m)
-    elif cfg.mlp_act == "silu":
-        x = x + L.swiglu_mlp(xin2, lp["mlp.w_gate"].astype(x.dtype),
-                             lp["mlp.w_up"].astype(x.dtype),
-                             lp["mlp.w_down"].astype(x.dtype))
-    else:
-        x = x + L.gelu_mlp(xin2, lp["mlp.w_in"].astype(x.dtype),
-                           lp["mlp.b_in"].astype(x.dtype),
-                           lp["mlp.w_out"].astype(x.dtype),
-                           lp["mlp.b_out"].astype(x.dtype))
-    return x, new_cache
+    xin2 = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_mlp"), eps)
+    y, touched = _mlp(cfg, xin2, lp, positions >= 0, dense=False)
+    return x + y, new_cache, touched
+
+
+def _mla_stacks(cfg: ModelConfig, params, x, positions, mode, pool, paged):
+    """The layers of a latent-attention model: the leading dense layers,
+    then the expert layers, each a scan over its own stacked weights.  The
+    latent pool (stacked over all layers) rides in the scans' carry and
+    each layer reads and writes its own slice in place.  Returns (x, pool,
+    distinct experts chosen per expert layer)."""
+    def run(stack, x, pool, first: int, dense: bool):
+        n = jax.tree.leaves(stack)[0].shape[0]
+
+        def body(carry, xs):
+            x, pool = carry
+            lp, layer = xs
+            xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_attn"),
+                               cfg.eps)
+            a, pool = _mla_attention(cfg, xin, lp, positions, mode, pool,
+                                     layer, paged)
+            x = x + a
+            xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_mlp"),
+                               cfg.eps)
+            y, touched = _mlp(cfg, xin, lp, positions >= 0, dense)
+            return (x + y, pool), touched
+
+        (x, pool), touched = jax.lax.scan(
+            body, (x, pool), (stack, first + jnp.arange(n, dtype=jnp.int32)))
+        return x, pool, touched
+
+    fd = cfg.first_dense_layers
+    if fd:
+        x, pool, _ = run(params["dense_layers"], x, pool, 0, True)
+    return run(params["layers"], x, pool, fd, False)
 
 
 # ============================== full forward ==================================
@@ -545,16 +742,17 @@ _LAYER_CACHE_KEYS = ("k", "v", "kq", "vq", "kscale", "vscale", "conv", "h")
 
 def forward(cfg: ModelConfig, params: PyTree, batch: Dict[str, jax.Array],
             mode: str = "train", cache: Optional[Dict[str, Any]] = None, *,
-            remat: bool = True, num_groups: int = 1,
-            dispatch_cs=MOE.Identity, combine_cs=MOE.Identity,
-            attn_fn=None, decode_attn_fn=None, scan_fn=None,
-            logits_cs=MOE.Identity, last_only: bool = False,
+            remat: bool = True, attn_fn=None, decode_attn_fn=None,
+            scan_fn=None, logits_cs=MOE.Identity, last_only: bool = False,
             unroll_layers: bool = False, extend_offset: int = 0,
             residual_cs=MOE.Identity, kv_cs=MOE.Identity,
             remat_policy: str = "nothing"
             ) -> Tuple[jax.Array, Optional[Dict[str, Any]]]:
     """Run the stack. batch: tokens (B,S) int32 | embeds (B,S,M); positions
-    (B,S). Returns (logits (B,S,V), new_cache or None)."""
+    (B,S). Returns (logits (B,S,V), new_cache or None).  For a model with
+    experts, a returned paged cache also holds "expert_loads": per expert
+    layer, the distinct experts the step's tokens (pads excluded) routed
+    to."""
     cd = _dt(cfg.compute_dtype)
     positions = batch["positions"]
 
@@ -594,35 +792,48 @@ def forward(cfg: ModelConfig, params: PyTree, batch: Dict[str, jax.Array],
 
     x = residual_cs(x)
 
-    def body(x, xs):
-        lp, cl = xs
-        cl = dict(cl)
-        if slot_pos is not None:
-            cl["slot_pos"] = slot_pos
-        if row_idx is not None:
-            cl["row_idx"] = row_idx
-        cl["idx"] = idx
-        y, nc = _block(cfg, x, lp, positions, mode, cl,
-                       num_groups=num_groups, dispatch_cs=dispatch_cs,
-                       combine_cs=combine_cs, attn_fn=attn_fn,
-                       decode_attn_fn=decode_attn_fn, scan_fn=scan_fn,
-                       extend_offset=extend_offset, kv_cs=kv_cs, paged=paged)
-        y = residual_cs(y)
-        nc = {k: nc[k] for k in _LAYER_CACHE_KEYS if k in nc}
-        return y, nc
+    if cfg.has_mla:
+        assert cache is None or paged is not None, \
+            "latent attention is served from the paged layout"
+        pool = None if cache is None else cache["ckv"]
+        x, pool, touched = _mla_stacks(cfg, params, x, positions, mode, pool,
+                                       paged)
+        new_layer_cache = {} if pool is None else {"ckv": pool}
+    else:
+        assert not cfg.first_dense_layers, \
+            "leading dense layers come with latent attention only"
 
-    policies = {
-        "nothing": jax.checkpoint_policies.nothing_saveable,
-        "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-    }
-    body_fn = jax.checkpoint(body, policy=policies[remat_policy]) \
-        if (remat and mode == "train") else body
+        def body(x, xs):
+            lp, cl = xs
+            cl = dict(cl)
+            if slot_pos is not None:
+                cl["slot_pos"] = slot_pos
+            if row_idx is not None:
+                cl["row_idx"] = row_idx
+            cl["idx"] = idx
+            y, nc, touched = _block(cfg, x, lp, positions, mode, cl,
+                                    attn_fn=attn_fn,
+                                    decode_attn_fn=decode_attn_fn,
+                                    scan_fn=scan_fn,
+                                    extend_offset=extend_offset, kv_cs=kv_cs,
+                                    paged=paged)
+            y = residual_cs(y)
+            nc = {k: nc[k] for k in _LAYER_CACHE_KEYS if k in nc}
+            return y, (nc, touched)
 
-    x, new_layer_cache = jax.lax.scan(body_fn, x, (stacked, layer_cache),
-                                      unroll=unroll_layers)
+        policies = {
+            "nothing": jax.checkpoint_policies.nothing_saveable,
+            "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        }
+        body_fn = jax.checkpoint(body, policy=policies[remat_policy]) \
+            if (remat and mode == "train") else body
+
+        x, (new_layer_cache, touched) = jax.lax.scan(
+            body_fn, x, (stacked, layer_cache), unroll=unroll_layers)
 
     fn_params = {k: v for k, v in params.items() if k.startswith("final_norm")}
-    x = L.apply_norm(cfg.norm_type, x, _norm_p(fn_params, "final_norm"))
+    x = L.apply_norm(cfg.norm_type, x, _norm_p(fn_params, "final_norm"),
+                     cfg.eps)
 
     if last_only:
         x = x[:, -1:]
@@ -633,6 +844,8 @@ def forward(cfg: ModelConfig, params: PyTree, batch: Dict[str, jax.Array],
     new_cache = None
     if mode != "train" and cache is not None:
         new_cache = dict(new_layer_cache)
+        if touched is not None and paged is not None:
+            new_cache["expert_loads"] = touched
         if mode == "decode":
             lc = cache["k"].shape[2] if "k" in cache else 0
             if slot_pos is not None:

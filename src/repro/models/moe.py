@@ -1,26 +1,22 @@
-"""Mixture-of-experts block: top-k routing with grouped, capacity-bounded
-scatter dispatch.
+"""Mixture-of-experts block: token-choice top-k routing with no dropped
+token, routed experts as one grouped matmul, and optional shared experts.
 
-Layout: tokens are split into `num_groups` groups (group axis shards over the
-`data` mesh axis). Within each group every (token, choice) pair gets a slot
-`expert * C + position_in_expert` via a cumsum over the one-hot routing
-matrix; overflow beyond capacity C is dropped and the gate weights are
-renormalized over surviving choices (standard capacity-factor dropping).
+Every token's top-k choices are sorted by expert and the sorted rows run
+through each expert's SwiGLU as a grouped matmul (`jax.lax.ragged_dot`,
+which lowers to a grouped-matmul kernel on the TPU and to a masked matmul
+elsewhere), so an expert reads its weights once for all the rows routed to
+it and no choice is ever dropped for capacity.  The rows are then weighted
+by their gates and summed back per token.
 
-Expert parallelism is injected from the distribution layer via `dispatch_cs`
-/ `combine_cs` sharding-constraint hooks:
-  EP (num_experts % 16 == 0): expert axis constrained to `model` → GSPMD
-      inserts the dispatch/return all-to-alls.
-  TP (small expert counts): per-expert FFN hidden dim sharded over `model`,
-      dispatch stays local to the data shard.
-
-FLOP note: dispatch/combine are scatters/gathers (no matmul FLOPs), so the
-compiled HLO FLOPs ≈ active-expert FLOPs × capacity_factor, keeping the
-roofline's useful-compute ratio honest (unlike dense all-experts fallbacks).
+Two routing rules (`norm_topk_prob`):
+  True  — softmax over the top-k router logits (Mixtral, Qwen3-MoE);
+  False — the top-k of a softmax over all experts, not renormalised
+          (DeepSeek-V2).
+Shared experts run on every token as one SwiGLU of their summed width.
 """
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,92 +24,62 @@ import jax.numpy as jnp
 Identity = lambda x: x
 
 
-def _round_up(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m
+def route(x: jax.Array, router: jax.Array, top_k: int,
+          norm_topk_prob: bool) -> Tuple[jax.Array, jax.Array]:
+    """(gates (T, K) float32, expert ids (T, K)) of tokens x (T, M)."""
+    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+    if norm_topk_prob:
+        top, idx = jax.lax.top_k(logits, top_k)
+        return jax.nn.softmax(top, axis=-1), idx
+    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
 
 
-def pick_num_groups(num_tokens: int, data_shards: int, target_group: int = 4096) -> int:
-    """Choose a group count that (a) divides the token count, (b) is a
-    multiple of the data-axis size when possible, (c) keeps groups ≈4k."""
-    g = max(1, num_tokens // target_group)
-    if g >= data_shards:
-        g = (g // data_shards) * data_shards
-    elif num_tokens % data_shards == 0 and num_tokens >= 4 * data_shards:
-        g = data_shards          # decode-sized batches: one group per shard
-    while num_tokens % g:
-        g -= 1
-    return max(1, g)
+def experts_touched(idx: jax.Array, num_experts: int,
+                    valid: jax.Array) -> jax.Array:
+    """Distinct experts the valid tokens (T,) chose, from ids (T, K)."""
+    hit = jnp.zeros((num_experts,), jnp.int32).at[idx].max(
+        jnp.broadcast_to(valid[:, None], idx.shape).astype(jnp.int32))
+    return hit.sum()
 
 
 def moe_block(x: jax.Array, params: dict, *, num_experts: int, top_k: int,
-              capacity_factor: float, num_groups: int = 1,
-              dispatch_cs: Callable = Identity, combine_cs: Callable = Identity,
-              compute_dtype=jnp.bfloat16) -> jax.Array:
-    """x: (T, M) token-major. params: router (M, E), w_gate/w_up (E, M, H),
-    w_down (E, H, M). Returns (T, M)."""
+              norm_topk_prob: bool = True,
+              compute_dtype=jnp.bfloat16) -> Tuple[jax.Array, jax.Array]:
+    """x (T, M) token-major. params: router (M, E), w_gate/w_up (E, M, F),
+    w_down (E, F, M), and with shared experts shared_gate/shared_up
+    (M, S·F), shared_down (S·F, M).  Returns (y (T, M), expert ids (T, K))."""
     T, M = x.shape
-    E, K = num_experts, top_k
-    G = num_groups
-    assert T % G == 0, (T, G)
-    Tg = T // G
-    C = max(4, _round_up(int(Tg * K * capacity_factor / E + 0.999), 4))
-    C = min(C, Tg * K)
-
-    # --- routing (fp32) ---
-    logits = (x.astype(jnp.float32) @ params["router"].astype(jnp.float32))
-    top_logits, top_idx = jax.lax.top_k(logits, K)          # (T, K)
-    gates = jax.nn.softmax(top_logits, axis=-1)             # renorm over top-k
-
-    xg = x.reshape(G, Tg, M)
-    idxg = top_idx.reshape(G, Tg * K)
-    gatesg = gates.reshape(G, Tg * K)
-
-    def dispatch_one(xs, idx):
-        # xs (Tg, M); idx (Tg*K,)
-        oh = jax.nn.one_hot(idx, E, dtype=jnp.int32)         # (Tg*K, E)
-        pos = jnp.cumsum(oh, axis=0) - 1                     # running count
-        pos = jnp.sum(pos * oh, axis=-1)                     # (Tg*K,)
-        keep = pos < C
-        dest = jnp.where(keep, idx * C + pos, E * C)         # overflow slot
-        x_rep = jnp.repeat(xs, K, axis=0)                    # (Tg*K, M)
-        buf = jnp.zeros((E * C + 1, M), compute_dtype)
-        buf = buf.at[dest].add(x_rep.astype(compute_dtype))
-        return buf[:-1], dest, keep
-
-    expert_in, dest, keep = jax.vmap(dispatch_one)(xg, idxg)   # (G, E*C, M)
-    expert_in = expert_in.reshape(G, E, C, M)
-    expert_in = dispatch_cs(expert_in)                          # EP all-to-all
-
-    wg = params["w_gate"].astype(compute_dtype)                 # (E, M, H)
-    wu = params["w_up"].astype(compute_dtype)
-    wd = params["w_down"].astype(compute_dtype)                 # (E, H, M)
-    h = jax.nn.silu(jnp.einsum("gecm,emh->gech", expert_in, wg))
-    h = h * jnp.einsum("gecm,emh->gech", expert_in, wu)
-    out = jnp.einsum("gech,ehm->gecm", h, wd)                   # (G, E, C, M)
-    out = combine_cs(out)                                       # return a2a
-
-    out_flat = out.reshape(G, E * C, M)
-
-    def combine_one(buf, dest, keep, gate):
-        # buf (E*C, M); dest/keep/gate (Tg*K,)
-        vals = jnp.take(buf, jnp.minimum(dest, E * C - 1), axis=0)
-        w = gate * keep.astype(gate.dtype)                      # drop overflow
-        denom = jnp.maximum(w.reshape(Tg, K).sum(-1, keepdims=True), 1e-9)
-        y = (vals.astype(jnp.float32).reshape(Tg, K, M)
-             * (w.reshape(Tg, K) / denom)[..., None]).sum(axis=1)
-        return y
-
-    y = jax.vmap(combine_one)(out_flat, dest, keep, gatesg)     # (G, Tg, M)
-    return y.reshape(T, M).astype(x.dtype)
+    with jax.named_scope("moe.route"):
+        gates, idx = route(x, params["router"], top_k, norm_topk_prob)
+        flat = idx.reshape(-1)                                   # (T·K,)
+        order = jnp.argsort(flat, stable=True)
+        rows = order // top_k                                    # token of row
+        sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+        xs = x.astype(compute_dtype)[rows]                       # (T·K, M)
+    with jax.named_scope("moe.experts"):
+        wg = params["w_gate"].astype(compute_dtype)
+        wu = params["w_up"].astype(compute_dtype)
+        wd = params["w_down"].astype(compute_dtype)
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, wg, sizes)) * \
+            jax.lax.ragged_dot(xs, wu, sizes)
+        out = jax.lax.ragged_dot(h, wd, sizes,
+                                 preferred_element_type=jnp.float32)
+        w = gates.reshape(-1)[order]
+        y = jnp.zeros((T, M), jnp.float32).at[rows].add(out * w[:, None])
+        if "shared_gate" in params:
+            xc = x.astype(compute_dtype)
+            hs = jax.nn.silu(xc @ params["shared_gate"].astype(compute_dtype)) \
+                * (xc @ params["shared_up"].astype(compute_dtype))
+            y = y + (hs @ params["shared_down"].astype(compute_dtype)
+                     ).astype(jnp.float32)
+    return y.astype(x.dtype), idx
 
 
-def moe_block_reference(x, params, *, num_experts, top_k, **_):
-    """Oracle: dense per-token loop over all experts (no capacity drops).
-    Used by tests to bound the capacity-dropping error of moe_block."""
-    T, M = x.shape
-    logits = x.astype(jnp.float32) @ params["router"].astype(jnp.float32)
-    top_logits, top_idx = jax.lax.top_k(logits, top_k)
-    gates = jax.nn.softmax(top_logits, axis=-1)
+def moe_block_reference(x, params, *, num_experts, top_k,
+                        norm_topk_prob: bool = True, **_):
+    """Oracle: every expert on every token in float32, then the gated sum
+    of each token's top-k (and the shared experts)."""
+    gates, idx = route(x, params["router"], top_k, norm_topk_prob)
     xf = x.astype(jnp.float32)
     wg = params["w_gate"].astype(jnp.float32)
     wu = params["w_up"].astype(jnp.float32)
@@ -121,5 +87,10 @@ def moe_block_reference(x, params, *, num_experts, top_k, **_):
     h = jax.nn.silu(jnp.einsum("tm,emh->teh", xf, wg))
     h = h * jnp.einsum("tm,emh->teh", xf, wu)
     all_out = jnp.einsum("teh,ehm->tem", h, wd)                 # (T, E, M)
-    sel = jnp.take_along_axis(all_out, top_idx[..., None], axis=1)  # (T, K, M)
-    return (sel * gates[..., None]).sum(axis=1).astype(x.dtype)
+    sel = jnp.take_along_axis(all_out, idx[..., None], axis=1)  # (T, K, M)
+    y = (sel * gates[..., None]).sum(axis=1)
+    if "shared_gate" in params:
+        hs = jax.nn.silu(xf @ params["shared_gate"].astype(jnp.float32)) * \
+            (xf @ params["shared_up"].astype(jnp.float32))
+        y = y + hs @ params["shared_down"].astype(jnp.float32)
+    return y.astype(x.dtype)

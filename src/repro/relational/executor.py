@@ -52,6 +52,10 @@ class ExecStats:
     decode_steps: int = 0           # decode ticks the engine ran
     decode_rows: int = 0            # live rows summed over those ticks
     decode_slots: int = 0           # decode-batch width summed over them
+    expert_loads_decode: int = 0    # distinct experts the paged decode
+    expert_slots_decode: int = 0    # steps touched, of expert layers x
+    expert_loads_prefill: int = 0   # experts x steps; the same for the
+    expert_slots_prefill: int = 0   # paged prefill steps
     # cascade accounting (CascadePredictor routes; zero for direct plans)
     proxy_calls: int = 0            # proxy-stage prompts scored
     escalated_calls: int = 0        # expensive-stage calls actually made
@@ -152,6 +156,10 @@ class PlanExecutor:
         self.stats.decode_steps += s.decode_steps
         self.stats.decode_rows += s.decode_rows
         self.stats.decode_slots += s.decode_slots
+        for f in dataclasses.fields(self.stats):
+            if f.name.startswith("expert_"):
+                setattr(self.stats, f.name,
+                        getattr(self.stats, f.name) + getattr(s, f.name))
         self.stats.proxy_calls += s.proxy_calls
         self.stats.escalated_calls += s.escalated_calls
         self.stats.cascade_rows += s.cascade_rows

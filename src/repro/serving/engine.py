@@ -37,6 +37,11 @@ from repro.serving.radix import RadixPrefixCache
 NEG_INF = -1e30
 
 
+def _pow2(n: int) -> int:
+    """The least power of two >= n (n >= 1)."""
+    return 1 << (n - 1).bit_length()
+
+
 def _bucket(n: int, buckets=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096)) -> int:
     for b in buckets:
         if n <= b:
@@ -57,6 +62,13 @@ class GenStats:
     radix_hit_tokens: int = 0      # prompt tokens served from the radix tree
     cow_copies: int = 0            # pages privatized by copy-on-write forks
     kv_bytes: int = 0              # peak KV-cache footprint (high-water)
+    # paged step programs of a model with experts: distinct experts the
+    # step's tokens routed to, summed over expert layers and steps, and
+    # the experts there were (expert layers x experts x steps)
+    expert_loads_decode: int = 0
+    expert_slots_decode: int = 0
+    expert_loads_prefill: int = 0
+    expert_slots_prefill: int = 0
 
     def add(self, other: "GenStats") -> None:
         for f in dataclasses.fields(self):
@@ -165,6 +177,16 @@ class InferenceEngine:
         assert kv_quant in ("none", "int8"), kv_quant
         if kv_layout == "paged":
             assert cfg.has_attention, "paged KV layout needs attention"
+        if cfg.has_mla:
+            for bad, why in ((kv_layout != "paged", f"kv_layout {kv_layout!r}"),
+                             (kv_quant != "none", f"kv_quant {kv_quant!r}"),
+                             (prefix_cache_mode != "radix",
+                              f"kv_prefix_mode {prefix_cache_mode!r}")):
+                if bad:
+                    raise ValueError(
+                        f"{cfg.name}: latent attention is served from the "
+                        f"paged layout's latent pool with the radix prefix "
+                        f"cache and no quantization, not {why}")
         self.cfg = cfg
         self.max_len = max_len
         #: the step programs' one weight tree (MDL.serving_params): leaves
@@ -188,6 +210,8 @@ class InferenceEngine:
         #: "int8": frozen (tree-committed) pages are quantized on commit to
         #: an int8 shadow pool with per-page scales; live pages stay fp
         self.kv_quant = kv_quant
+        #: the full-precision pools of the paged layout
+        self.pool_keys = ("ckv",) if cfg.has_mla else ("k", "v")
         #: per-row block-table width: max_len tokens worth of pages
         self.num_table_blocks = max(1, -(-max_len // self.page_size))
         self._prefill_cache: Dict[Tuple, object] = {}
@@ -269,6 +293,34 @@ class InferenceEngine:
                                             donate_argnums=(3,))
         return self._decode_fns[key]
 
+    def _paged_prefill_fn(self, batch: int, length: int, num_blocks: int,
+                          prefix_pages: int):
+        """Paged prefill step, jit-cached per (batch, bucketed length, table
+        width, shared prefix pages)."""
+        key = ("paged", batch, length, num_blocks, prefix_pages)
+        if key not in self._prefill_cache:
+            cfg = self.cfg
+
+            # block table / prefix table / quant flags ride OUTSIDE the
+            # donated cache: they are rebuilt host-side every call,
+            # donation buys nothing
+            def paged_prefill_step(params, tokens, positions, cache, bt,
+                                   ptab, plen, qf):
+                cache = dict(cache, block_tables=bt, prefix_table=ptab,
+                             prefix_len=plen)
+                if qf is not None:
+                    cache["quant_flags"] = qf
+                logits, cache = MDL.forward(
+                    cfg, params,
+                    {"tokens": tokens, "positions": positions},
+                    mode="prefill", cache=cache, remat=False,
+                    last_only=True)
+                return logits[:, -1], cache
+
+            self._prefill_cache[key] = jax.jit(paged_prefill_step,
+                                               donate_argnums=(3,))
+        return self._prefill_cache[key]
+
     # ------------------------------- prefill ----------------------------------
     def _prefill(self, token_lists: List[List[int]], *, offset: int = 0,
                  pos_offset: Optional[int] = None,
@@ -305,8 +357,9 @@ class InferenceEngine:
     def _page_bytes(self) -> int:
         cfg = self.cfg
         itemsize = 2 if cfg.compute_dtype in ("bfloat16", "float16") else 4
-        return (2 * cfg.num_layers * self.page_size * cfg.num_kv_heads
-                * cfg.head_dim * itemsize)
+        per_token = cfg.latent_dim if cfg.has_mla else \
+            2 * cfg.num_kv_heads * cfg.head_dim
+        return cfg.num_layers * self.page_size * per_token * itemsize
 
     def _page_bytes_quant(self) -> int:
         """Logical bytes of a frozen int8 page (scales are negligible)."""
@@ -350,7 +403,7 @@ class InferenceEngine:
             return
         s = jnp.asarray(srcs, jnp.int32)
         d = jnp.asarray(dsts, jnp.int32)
-        for kk in ("k", "v"):
+        for kk in self.pool_keys:
             self._pool[kk] = self._pool[kk].at[:, :, d].set(
                 self._pool[kk][:, :, s])
 
@@ -434,11 +487,10 @@ class InferenceEngine:
         order.sort(key=lambda t: len(t[1]))
         for node, path in order:
             pg = np.asarray(node.pages, np.int64)
-            entries.append({
-                "path": list(path),
-                "k": np.asarray(self._pool["k"][:, :, pg]),
-                "v": np.asarray(self._pool["v"][:, :, pg]),
-            })
+            ent = {"path": list(path)}
+            ent.update({kk: np.asarray(self._pool[kk][:, :, pg])
+                        for kk in self.pool_keys})
+            entries.append(ent)
         if not entries:
             return None
         return {"page_size": self.page_size, "entries": entries}
@@ -457,8 +509,9 @@ class InferenceEngine:
         pages_for_path: Dict[Tuple[int, ...], List[int]] = {(): []}
         for ent in payload.get("entries", []):
             path = tuple(int(t) for t in ent["path"])
-            k_host, v_host = ent["k"], ent["v"]
-            own_np = int(k_host.shape[2])
+            if any(kk not in ent for kk in self.pool_keys):
+                continue               # another pool layout: skip
+            own_np = int(ent[self.pool_keys[0]].shape[2])
             parent_path = path[: len(path) - own_np * ps]
             parent_pages = pages_for_path.get(parent_path)
             if parent_pages is None or len(path) % ps:
@@ -467,10 +520,9 @@ class InferenceEngine:
                 break                  # pinned pool exhausted: partial warm
             own = self.alloc_pages(own_np)
             pg = jnp.asarray(own, jnp.int32)
-            self._pool["k"] = self._pool["k"].at[:, :, pg].set(
-                jnp.asarray(k_host, self._pool["k"].dtype))
-            self._pool["v"] = self._pool["v"].at[:, :, pg].set(
-                jnp.asarray(v_host, self._pool["v"].dtype))
+            for kk in self.pool_keys:
+                self._pool[kk] = self._pool[kk].at[:, :, pg].set(
+                    jnp.asarray(ent[kk], self._pool[kk].dtype))
             full_pages = list(parent_pages) + list(own)
             self.radix_insert(list(path), full_pages)
             # the tree now holds its own reference to the adopted pages;
@@ -501,8 +553,8 @@ class InferenceEngine:
                 n = max(n, need_pages)
             full = MDL.init_paged_cache(self.cfg, n, self.page_size,
                                         quant=quant)
-            keys = ("k", "v") + (("kq", "vq", "kscale", "vscale")
-                                 if quant else ())
+            keys = self.pool_keys + (("kq", "vq", "kscale", "vscale")
+                                     if quant else ())
             self._pool = {kk: full[kk] for kk in keys}
             self._alloc = PageAllocator(n)
             if quant:
@@ -658,14 +710,33 @@ class InferenceEngine:
         return list(ent.pages), n_share, ids[n_share:]
 
     # ----------------------------- paged prefill ------------------------------
+    def _fetch(self, logits, out: dict, stats: Optional[GenStats],
+               kind: str) -> np.ndarray:
+        """The step's logits to the host, with its expert loads (one
+        transfer; a model without experts returns none) added to
+        ``stats``."""
+        loads = out.pop("expert_loads", None)
+        if loads is None:
+            return np.asarray(logits, np.float32)
+        lg, loads = jax.device_get((logits, loads))
+        if stats is not None:
+            cfg = self.cfg
+            setattr(stats, f"expert_loads_{kind}",
+                    getattr(stats, f"expert_loads_{kind}") + int(loads.sum()))
+            setattr(stats, f"expert_slots_{kind}",
+                    getattr(stats, f"expert_slots_{kind}")
+                    + cfg.num_moe_layers * cfg.num_experts)
+        return np.asarray(lg, np.float32)
+
     def paged_prefill(self, token_lists: List[List[int]], table_rows,
                       prefix_pages: Sequence[int], prefix_len: int, *,
-                      extra: Optional[dict] = None):
+                      extra: Optional[dict] = None,
+                      stats: Optional[GenStats] = None):
         """Prefill suffixes straight into their block-table pages, reading
         shared prefix pages in place (no per-row replication).  table_rows:
         np.ndarray (B, NB) page ids.  Returns (logits, lens, prefill_token
         count, extra_out) — extra carries per-row SSM state for hybrid
-        models."""
+        models; the step's expert loads are added to ``stats``."""
         with span("engine.prefill"):
             B = len(token_lists)
             L = _bucket(max(len(t) for t in token_lists))
@@ -676,52 +747,39 @@ class InferenceEngine:
                 toks[i, pad:] = t
                 pos[i] = np.arange(L) - pad + prefix_len
                 pos[i, :pad] = -1
+            # the prefix table is padded (-1) to a power of two, which the
+            # step masks by prefix_len: a radix match a page deeper than the
+            # shared instruction reuses the instruction's program instead of
+            # compiling one of its own
             npre = len(prefix_pages)
+            ptab = np.full(_pow2(npre) if npre else 0, -1, np.int32)
+            ptab[:npre] = prefix_pages
             cache = dict(self._pool, idx=jnp.int32(0))
             if extra:
                 cache.update(extra)
-            key = ("paged", B, L, table_rows.shape[1], npre)
-            if key not in self._prefill_cache:
-                cfg = self.cfg
-
-                # block table / prefix table / quant flags ride OUTSIDE the
-                # donated cache: they are rebuilt host-side every call,
-                # donation buys nothing
-                def paged_prefill_step(params, tokens, positions, cache, bt,
-                                       ptab, plen, qf):
-                    cache = dict(cache, block_tables=bt, prefix_table=ptab,
-                                 prefix_len=plen)
-                    if qf is not None:
-                        cache["quant_flags"] = qf
-                    logits, cache = MDL.forward(
-                        cfg, params,
-                        {"tokens": tokens, "positions": positions},
-                        mode="prefill", cache=cache, remat=False,
-                        last_only=True)
-                    return logits[:, -1], cache
-
-                self._prefill_cache[key] = jax.jit(paged_prefill_step,
-                                                   donate_argnums=(3,))
             qf = None if self._quant_flags is None \
                 else jnp.asarray(self._quant_flags)
-            logits, out = self._prefill_cache[key](
+            logits, out = self._paged_prefill_fn(
+                B, L, table_rows.shape[1], len(ptab))(
                 self.params, jnp.asarray(toks), jnp.asarray(pos), cache,
                 jnp.asarray(np.ascontiguousarray(table_rows)),
-                jnp.asarray(np.asarray(prefix_pages, np.int32).reshape(npre)),
-                jnp.int32(prefix_len), qf)
+                jnp.asarray(ptab), jnp.int32(prefix_len), qf)
             for kk in self._pool:
                 self._pool[kk] = out[kk]
             extra_out = {k: out[k] for k in ("conv", "h") if k in out}
             lens = np.array([prefix_len + len(t) for t in token_lists],
                             np.int32)
-            return np.asarray(logits, np.float32), lens, B * L, extra_out
+            return self._fetch(logits, out, stats, "prefill"), lens, B * L, \
+                extra_out
 
     def paged_decode(self, toks, positions, table, num_blocks: int, *,
-                     extra: Optional[dict] = None):
+                     extra: Optional[dict] = None,
+                     stats: Optional[GenStats] = None):
         """One lock-step decode tick against the page pool.  `table` is the
         host block table (B, NB_full); only its first `num_blocks` columns
         (the batch's actual fill, bucketed by the caller) reach the device,
-        so attention work scales with occupancy, not max_len."""
+        so attention work scales with occupancy, not max_len.  The step's
+        expert loads are added to ``stats``."""
         cache = dict(self._pool, idx=jnp.int32(0))
         if extra:
             cache.update(extra)
@@ -735,16 +793,13 @@ class InferenceEngine:
         for kk in self._pool:
             self._pool[kk] = out[kk]
         extra_out = {k: out[k] for k in ("conv", "h") if k in out}
-        return np.asarray(lg, np.float32), extra_out
+        return self._fetch(lg, out, stats, "decode"), extra_out
 
     def active_blocks(self, fills) -> int:
         """Bucketed block count covering the given fill levels (pow-2 so
         decode-step jit caches stay few)."""
         need = max(1, max((int(f) // self.page_size) + 1 for f in fills))
-        nb = 1
-        while nb < need:
-            nb *= 2
-        return min(nb, self.num_table_blocks)
+        return min(_pow2(need), self.num_table_blocks)
 
     # ------------------------------- generate ---------------------------------
     @staticmethod
@@ -888,7 +943,7 @@ class InferenceEngine:
                 st[0, n_share // ps:aligned // ps] = seed
                 _, _, pre, _ = self.paged_prefill(
                     [common[n_share:aligned]], st, pages_pre, n_share,
-                    extra=self._ssm_state(1))
+                    extra=self._ssm_state(1), stats=stats)
                 stats.prefill_tokens += pre
                 self.radix_insert(common[:aligned],
                                   list(st[0, :aligned // ps]))
@@ -928,7 +983,8 @@ class InferenceEngine:
 
             extra = self._ssm_state(B)
             logits, lens, pre, extra = self.paged_prefill(
-                token_lists, table, pages_pre, n_share, extra=extra)
+                token_lists, table, pages_pre, n_share, extra=extra,
+                stats=stats)
             stats.prefill_tokens += pre
             if self.prefix_cache_mode == "radix":
                 # commit every row's full-page prompt span (clamped to the
@@ -956,7 +1012,8 @@ class InferenceEngine:
                     with span("engine.step"):
                         nb = self.active_blocks(positions[~done])
                         logits, extra = self.paged_decode(
-                            toks, positions, table, nb, extra=extra)
+                            toks, positions, table, nb, extra=extra,
+                            stats=stats)
                     positions += 1
         finally:
             # errors must not leak refcounts: a pinned pool would shrink

@@ -390,7 +390,7 @@ class ContinuousBatcher:
                 or None
             lg, lens, pre, ex1 = eng.paged_prefill(
                 [suffix], table[b:b + 1], pre_pages, pre_len,
-                extra=slot_extra)
+                extra=slot_extra, stats=st)
             if extra:
                 extra = {k: extra[k].at[:, b:b + 1].set(ex1[k])
                          for k in extra}
@@ -485,7 +485,8 @@ class ContinuousBatcher:
                     with span("engine.step"):
                         nb = eng.active_blocks(positions[live])
                         lgn, extra_out = eng.paged_decode(
-                            toks, positions, table, nb, extra=extra)
+                            toks, positions, table, nb, extra=extra,
+                            stats=st)
                     if extra:
                         extra = extra_out
                     for b in range(B):

@@ -364,3 +364,27 @@ def test_sql_n_samples_self_consistency():
     tree = eng._radix.resident_page_ids()
     assert eng._alloc.in_use == len(tree)
     db.close()
+
+
+def test_deeper_radix_match_reuses_the_prefill_program():
+    """Prefix tables are padded to a power of two (masked by prefix_len):
+    a fill that matches one page deeper than the shared instruction runs
+    the instruction's prefill program.  (That the padded pages are never
+    read is checked against the float32 references with shared prefixes,
+    bench/tests/test_bench_reference.py and test_bench_deepseek.py.)"""
+    cfg = C.get_smoke_config("olmo-1b").replace(vocab_size=259)
+    eng = InferenceEngine(cfg, seed=3, max_len=128, kv_layout="paged",
+                          page_size=4)
+    eng._ensure_pool(64)
+    prefix = eng.alloc_pages(7)
+    suffix = list(range(40, 50))
+    table = np.full((1, eng.num_table_blocks), -1, np.int32)
+    out = {}
+    for npre in (6, 7):
+        table[0, :npre] = prefix[:npre]
+        table[0, npre:npre + 3] = eng.alloc_pages(3)
+        out[npre] = eng.paged_prefill([suffix], table, prefix[:npre],
+                                      npre * 4)[0]
+    assert [k[4] for k in eng._prefill_cache if k[0] == "paged"] == [8]
+    # the same suffix one page later: another answer, the same program
+    assert not np.allclose(out[6], out[7])

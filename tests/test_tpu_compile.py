@@ -155,3 +155,35 @@ def test_full_width_prefill_step_takes_bf16_weights(one_chip):
                                                 cache).compile()
     assert _float32_weights(compiled) == []
     assert eng.param_bytes == MDL.tree_bytes(eng.params) < 2.5e9
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_full_width_latent_steps_fit_one_chip(one_chip, step):
+    """DeepSeek-V2-Lite's first seven layers at published widths: the
+    docs-wide cell's paged steps (32 slots, 1024 pages of 64 latents) hold
+    the bf16 weights and the latent pool on one chip, donate the pool in
+    place, and run the routed experts as a grouped-matmul kernel."""
+    cfg = C.get_config("deepseek-v2-lite@7")
+    eng = InferenceEngine(cfg, params=_on(one_chip, MDL.param_specs(cfg)),
+                          max_len=MAX_LEN, kv_layout="paged", page_size=PAGE)
+    P, NB, B = 1024, MAX_LEN // PAGE, 32
+    pool = _on(one_chip, MDL.paged_cache_specs(cfg, P, PAGE, B))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if step == "decode":
+        lowered = eng._decode_fn_paged(NB).lower(
+            eng.params, i32(B, 1), i32(B, 1), pool, i32(B, NB), None)
+    else:
+        # the instruction's 6 shared pages, in a prefix table of 8
+        lowered = eng._paged_prefill_fn(1, 1024, NB, 8).lower(
+            eng.params, i32(1, 1024), i32(1, 1024), pool, i32(1, NB), i32(8),
+            i32(), None)
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()      # ragged_dot kernel
+    mem = compiled.memory_analysis()
+    # 8.02 GB of bf16 weights, the 0.59 GB latent pool aliased in place
+    assert 8.0e9 < eng.param_bytes < 8.1e9
+    assert mem.alias_size_in_bytes >= MDL.tree_bytes(pool["ckv"])
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10e9
