@@ -112,7 +112,7 @@ def decode_attention(q, k_cache, v_cache, slot_positions, q_position, *,
 @functools.partial(jax.jit, static_argnames=("head_dim", "interpret"))
 def decode_attention_paged(q, k_pool, v_pool, block_tables, q_position, *,
                            head_dim: Optional[int] = None, quant=None,
-                           interpret: Optional[bool] = None):
+                           layer=None, interpret: Optional[bool] = None):
     """Paged decode attention over the pre-folded pool layout: q (B, H, D);
     pools (KV, P, ps, Dp) with Dp = head_dim already zero-padded to the
     128-lane width, so the kernel's (KV·P, ps, Dp) view is a FREE reshape —
@@ -129,17 +129,21 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, q_position, *,
 
     quant (dict or None): int8 shadow pools "kq"/"vq" (KV, P, ps, Dp),
     per-page scales "kscale"/"vscale" (KV, P) and frozen flags "flags"
-    (P,) — dispatches to the dequantizing kernel twin."""
+    (P,) — dispatches to the dequantizing kernel twin.
+
+    With `layer`, the pools, shadow pools and scales are stacked over
+    layers on a leading axis: the kernel reads the pages of `layer` through
+    the same free view of the whole stack, offset by the layer's pages."""
     interpret = use_interpret() if interpret is None else interpret
     B, H, D = q.shape
-    KV, P, ps, Dp = k_pool.shape
+    KV, P, ps, Dp = k_pool.shape[-4:]
     D = head_dim or D
     NB = block_tables.shape[1]
     G = H // KV
 
     qf = _pad_axis(q, 2, Dp).reshape(B, KV, G, Dp).reshape(B * KV, G, Dp)
-    kf = k_pool.reshape(KV * P, ps, Dp)
-    vf = v_pool.reshape(KV * P, ps, Dp)
+    kf = k_pool.reshape(-1, ps, Dp)
+    vf = v_pool.reshape(-1, ps, Dp)
 
     qpos = q_position.astype(jnp.int32)
     nact = jnp.clip(jnp.clip(qpos, 0, None) // ps + 1, 1, NB)       # (B,)
@@ -148,6 +152,8 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, q_position, *,
     bt = jnp.where((idxs < nact[:, None]) & (block_tables >= 0),
                    block_tables, last)
     bt = jnp.clip(bt, 0, P - 1)
+    if layer is not None:
+        bt = bt + layer * KV * P
     btf = (bt[:, None, :] +
            jnp.arange(KV, dtype=jnp.int32)[None, :, None] * P
            ).reshape(B * KV, NB)
@@ -156,12 +162,12 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, q_position, *,
 
     scale = 1.0 / (D ** 0.5)
     if quant is not None:
-        kqf = quant["kq"].reshape(KV * P, ps, Dp)
-        vqf = quant["vq"].reshape(KV * P, ps, Dp)
-        ksf = quant["kscale"].reshape(KV * P, 1).astype(jnp.float32)
-        vsf = quant["vscale"].reshape(KV * P, 1).astype(jnp.float32)
+        kqf = quant["kq"].reshape(-1, ps, Dp)
+        vqf = quant["vq"].reshape(-1, ps, Dp)
+        ksf = quant["kscale"].reshape(-1, 1).astype(jnp.float32)
+        vsf = quant["vscale"].reshape(-1, 1).astype(jnp.float32)
         flf = jnp.tile(quant["flags"].astype(jnp.int32)[None, :],
-                       (KV, 1)).reshape(KV * P, 1)
+                       (ksf.shape[0] // P, 1)).reshape(-1, 1)
         o = decode_attention_paged_quant_pallas(
             qf, kf, vf, kqf, vqf, ksf, vsf, flf, btf, nactf, qposf,
             scale=scale, interpret=interpret)

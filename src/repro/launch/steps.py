@@ -196,7 +196,7 @@ def make_decode_step(cfg: ModelConfig, mesh: Optional[Mesh], shape: ShapeSpec,
 
     cache_shard_mode='lc' + per_row_write=True is §Perf opt B: cache length
     sharded over `model` (softmax partials → tiny collectives) with the
-    slot write as a masked elementwise update (no DUS on a sharded dim)."""
+    slot write as a per-row scatter (no DUS on a sharded dim)."""
     assert cfg.supports_decode, f"{cfg.name} has no decode step"
     batch_specs = CC.decode_batch_specs(cfg, shape.global_batch)
     cache_specs = MDL.cache_specs(cfg, shape.global_batch, shape.seq_len,

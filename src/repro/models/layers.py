@@ -369,13 +369,26 @@ def decode_attention(q, k_cache, v_cache, cache_positions, q_position, *,
 
 
 # -- paged decode attention (block-table addressed page pool) -------------------
+def gather_pages(pool, pages, layer=None):
+    """pool[:, pages] of a (KV, P, ...) pool: (KV, *pages.shape, ...).  With
+    `layer`, the pool is stacked over layers (layers, KV, P, ...) and the
+    pages are read at `layer` in one gather from the stack, without copying
+    the layer's slice out first."""
+    if layer is None:
+        return pool[:, pages]
+    heads = jnp.arange(pool.shape[1]).reshape((-1,) + (1,) * pages.ndim)
+    return pool[layer, heads, pages[None]]
+
+
 def decode_attention_paged(q, k_pool, v_pool, block_tables, q_position, *,
-                           head_dim=None, quant=None):
+                           head_dim=None, quant=None, layer=None):
     """q (B, H, D); pools pre-folded (KV, P, ps, Dp) with Dp = head_dim
     zero-padded to the 128-lane width — a GLOBAL page pool shared by all
     sequences (and, for a shared instruction prefix, by all batch rows);
     block_tables (B, NB) int32 page ids (-1 = invalid entry); q_position
-    (B,). Returns (B, H, D).
+    (B,). Returns (B, H, D).  With `layer`, the pools (and quant's shadow
+    pools and scales) are stacked over layers on a leading axis and read at
+    `layer` (`gather_pages`).
 
     Paged-layout invariant: logical slot index == absolute token position,
     so slot validity is just `index <= q_position` plus table-entry
@@ -388,18 +401,20 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, q_position, *,
     lives in kernels/decode_attention.
     """
     B, H, D = q.shape
-    KV, P, ps, Dp = k_pool.shape
+    KV, P, ps, Dp = k_pool.shape[-4:]
     D = head_dim or D
     NB = block_tables.shape[1]
     safe = jnp.clip(block_tables, 0, P - 1)
-    k = k_pool[:, safe]                               # (KV, B, NB, ps, Dp)
-    v = v_pool[:, safe]
+    k = gather_pages(k_pool, safe, layer)             # (KV, B, NB, ps, Dp)
+    v = gather_pages(v_pool, safe, layer)
     if quant is not None:
         fl = (quant["flags"][safe] > 0)[None, :, :, None, None]
-        kdq = (quant["kq"][:, safe].astype(jnp.float32)
-               * quant["kscale"][:, safe][..., None, None]).astype(k.dtype)
-        vdq = (quant["vq"][:, safe].astype(jnp.float32)
-               * quant["vscale"][:, safe][..., None, None]).astype(v.dtype)
+        kdq = (gather_pages(quant["kq"], safe, layer).astype(jnp.float32)
+               * gather_pages(quant["kscale"], safe, layer)[..., None, None]
+               ).astype(k.dtype)
+        vdq = (gather_pages(quant["vq"], safe, layer).astype(jnp.float32)
+               * gather_pages(quant["vscale"], safe, layer)[..., None, None]
+               ).astype(v.dtype)
         k = jnp.where(fl, kdq, k)
         v = jnp.where(fl, vdq, v)
     k = k.transpose(1, 2, 3, 0, 4).reshape(B, NB * ps, KV, Dp)[..., :D]

@@ -242,10 +242,11 @@ def param_count_actual(params: PyTree) -> int:
 # ================================ cache =======================================
 def cache_specs(cfg: ModelConfig, batch: int, cache_len: int,
                 include_row_idx: bool = False) -> Dict[str, Any]:
-    """ShapeDtypeStructs for the decode cache. include_row_idx adds the
-    per-row write cursor (continuous batching / sharded-length caches —
-    the write becomes a masked elementwise update instead of a DUS on a
-    sharded dim)."""
+    """ShapeDtypeStructs for the decode cache, each layer's K/V (or SSM
+    state) stacked on a leading axis. include_row_idx adds the per-row
+    write cursor (continuous batching / sharded-length caches — each row's
+    new K/V is scattered into its own slot instead of one dynamic update at
+    a shared cursor)."""
     ln, cd = cfg.num_layers, _dt(cfg.compute_dtype)
     out: Dict[str, Any] = {"idx": jax.ShapeDtypeStruct((), jnp.int32)}
     if include_row_idx:
@@ -340,14 +341,73 @@ def _norm_p(lp: Dict[str, jax.Array], prefix: str) -> Optional[dict]:
     return {"scale": scale, "bias": bias}
 
 
-def _fold_write(x: jax.Array, dp: int) -> jax.Array:
-    """(..., KV, D) → (KV, ..., Dp): move the kv-head axis to the front and
-    zero-pad head_dim to the pool's padded lane width."""
-    x = jnp.moveaxis(x, -2, 0)
-    pad = dp - x.shape[-1]
+def _write_pages(pool: jax.Array, layer, page: jax.Array, off: jax.Array,
+                 x: jax.Array) -> jax.Array:
+    """Write x (..., KV, D), the K or V of the tokens at (page, off) (each
+    of shape (...)), into the pool (layers, KV, P, ps, Dp) at `layer`, in
+    place, head_dim zero-padded to Dp.  Every (layer, kv-head, page,
+    offset) is an index of its own, so a token's write is one row of Dp
+    lanes, and an invalid page id (P, out of bounds) drops that token's
+    write and can never fold into a neighbouring kv-head's page 0."""
+    pad = pool.shape[-1] - x.shape[-1]
     if pad:
         x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-    return x
+    head = jnp.arange(pool.shape[1], dtype=jnp.int32)
+    return pool.at[layer, head, page[..., None], off[..., None]].set(
+        x.astype(pool.dtype), mode="drop")
+
+
+def _write_prefill_pages(pool: jax.Array, layer, bt: jax.Array,
+                         positions: jax.Array, x: jax.Array) -> jax.Array:
+    """Write x (B, S, KV, D), the K or V of a prefill's tokens, into the pool
+    (layers, KV, P, ps, Dp) at `layer`, in place, a whole page at a time.
+    A row's tokens sit at consecutive positions behind its left pads
+    (position -1), as `InferenceEngine.paged_prefill` lays them out.  Each
+    row reads the pages its tokens fall in, lays the tokens over them and
+    writes the pages back: one index per (kv-head, page) instead of one
+    per (kv-head, token), which the TPU's scatter runs many times faster.
+    A page with no new token, or with no table entry, is not written, so
+    writes are dropped exactly where `_page_slots` drops them."""
+    B, S = positions.shape
+    KV, P, ps, Dp = pool.shape[1:]
+    nb = bt.shape[1]
+    win = (-(-S // ps) + 1) * ps             # S consecutive tokens, any start
+    n = jnp.sum(positions >= 0, axis=1)                            # (B,)
+    first = positions[:, -1] - n + 1
+    blk0 = jnp.clip(first, 0, None) // ps
+    lead = first - blk0 * ps
+    blocks = blk0[:, None] + jnp.arange(win // ps)                 # (B, nw)
+    page = jnp.take_along_axis(bt, jnp.clip(blocks, 0, nb - 1), axis=1)
+    new_in = (blocks * ps < (first + n)[:, None]) & (blocks < nb) & \
+        (page >= 0)
+    page = jnp.where(new_in, page, P)
+    old = L.gather_pages(pool, jnp.clip(page, 0, P - 1), layer)
+    old = old.reshape(KV, B, win, Dp)
+    # window slot w holds token column w - lead + (S - n): shift each row's
+    # tokens by a slice of the row padded with a page in front
+    x = jnp.pad(x.astype(pool.dtype),
+                [(0, 0), (ps, win), (0, 0), (0, Dp - x.shape[-1])])
+    start = ps + S - n - lead
+    new = jax.vmap(lambda r, s: jax.lax.dynamic_slice_in_dim(r, s, win))(
+        x, start)                                         # (B, win, KV, Dp)
+    w = jnp.arange(win)[None]
+    mine = (w >= lead[:, None]) & (w < (lead + n)[:, None])        # (B, win)
+    new = jnp.where(mine[None, :, :, None], new.transpose(2, 0, 1, 3), old)
+    head = jnp.arange(KV, dtype=jnp.int32)[:, None, None]
+    return pool.at[layer, head, page[None]].set(
+        new.reshape(KV, B, -1, ps, Dp), mode="drop")
+
+
+def _layer(stack: jax.Array, layer) -> jax.Array:
+    """One layer's slice of a stacked cache (layers, ...)."""
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
+def _put(stack: jax.Array, layer, vals: jax.Array, *start) -> jax.Array:
+    """Write vals, a block of one layer's slice, into the stacked cache at
+    (layer, *start), in place."""
+    return jax.lax.dynamic_update_slice(
+        stack, vals[None].astype(stack.dtype), (layer, *start))
 
 
 def _page_slots(bt: jax.Array, positions: jax.Array, num_pages: int,
@@ -369,34 +429,41 @@ def _page_slots(bt: jax.Array, positions: jax.Array, num_pages: int,
 
 
 def _dequant_pages(qd: Dict[str, jax.Array], safe_pages: jax.Array,
-                   kp: jax.Array, vp: jax.Array):
+                   kp: jax.Array, vp: jax.Array, layer):
     """Replace frozen (quantized) pages of a gathered fp view with their
     dequantized int8 shadow.  safe_pages (npre,) clipped page ids;
-    kp/vp (KV, npre, ps, Dp) gathered fp pages."""
+    kp/vp (KV, npre, ps, Dp) gathered fp pages; the shadow pools and scales
+    are stacked over layers and read at `layer`."""
     fl = qd["flags"][safe_pages] > 0                       # (npre,)
-    kdq = (qd["kq"][:, safe_pages].astype(jnp.float32)
-           * qd["kscale"][:, safe_pages][..., None, None]).astype(kp.dtype)
-    vdq = (qd["vq"][:, safe_pages].astype(jnp.float32)
-           * qd["vscale"][:, safe_pages][..., None, None]).astype(vp.dtype)
+    kdq = (L.gather_pages(qd["kq"], safe_pages, layer).astype(jnp.float32)
+           * L.gather_pages(qd["kscale"], safe_pages, layer)[..., None, None]
+           ).astype(kp.dtype)
+    vdq = (L.gather_pages(qd["vq"], safe_pages, layer).astype(jnp.float32)
+           * L.gather_pages(qd["vscale"], safe_pages, layer)[..., None, None]
+           ).astype(vp.dtype)
     kp = jnp.where(fl[None, :, None, None], kdq, kp)
     vp = jnp.where(fl[None, :, None, None], vdq, vp)
     return kp, vp
 
 
-def _attention(cfg: ModelConfig, x, lp, positions, mode, ck, cv, slot_pos, idx,
-               attn_fn=None, decode_attn_fn=None, extend_offset: int = 0,
-               row_idx=None, kv_cs=MOE.Identity, paged=None):
-    """x (B,S,M). Returns (out (B,S,M), new_ck, new_cv).
+def _attention(cfg: ModelConfig, x, lp, positions, mode, ck, cv, layer,
+               slot_pos, idx, attn_fn=None, decode_attn_fn=None,
+               extend_offset: int = 0, row_idx=None, kv_cs=MOE.Identity,
+               paged=None):
+    """x (B,S,M). Returns (out (B,S,M), ck, cv).
+    ck/cv (None in train mode) are the caches of every layer, stacked on a
+    leading axis: attention reads the slice at `layer` and writes only the
+    new tokens' K/V into it, in place.
     extend_offset > 0 (prefill mode): attend over [cache[:offset] ++ new] and
     write the new K/V at slot offset — chunked prefill / shared-prefix reuse.
     paged (dict or None): block-table addressed page-pool layout — ck/cv are
-    then pre-folded (KV, P, ps, Dp) pools (Dp = head_dim padded to 128),
-    paged["block_tables"] is (B, NB) page ids
+    then pre-folded (layers, KV, P, ps, Dp) pools (Dp = head_dim padded to
+    128), paged["block_tables"] is (B, NB) page ids
     (-1 = invalid; invalid/out-of-range writes are dropped), prefill may
     carry paged["prefix_table"]/["prefix_len"] pointing at shared prefix
     pages that are read in place, never replicated per row, and
-    paged["quant"] (if set) holds int8 shadow pools + per-page scales +
-    frozen flags for dequantizing committed shared pages on read."""
+    paged["quant"] (if set) holds the stacked int8 shadow pools + per-page
+    scales + frozen flags for dequantizing committed shared pages on read."""
     B, S, m = x.shape
     h, kv, hd = cfg.padded_heads, cfg.num_kv_heads, cfg.head_dim
     cd = _dt(cfg.compute_dtype)
@@ -413,98 +480,96 @@ def _attention(cfg: ModelConfig, x, lp, positions, mode, ck, cv, slot_pos, idx,
         k = kv_cs(k)        # sequence-parallel attention: kv replicated
         v = kv_cs(v)
 
-    new_ck, new_cv = ck, cv
     if paged is not None and mode == "decode":
         bt = paged["block_tables"]
-        KV_, P_, ps_, Dp_ = ck.shape
+        P_, ps_ = ck.shape[2:4]
         pos = positions[:, 0]                                     # (B,)
         page, off = _page_slots(bt, pos, P_, ps_)
-        # per-axis indexing keeps the P_ out-of-bounds drop trick safe: the
-        # page axis is indexed on its own, so an invalid id can never fold
-        # into a neighbouring kv-head's page 0
-        new_ck = ck.at[:, page, off].set(
-            _fold_write(k[:, 0], Dp_).astype(ck.dtype), mode="drop")
-        new_cv = cv.at[:, page, off].set(
-            _fold_write(v[:, 0], Dp_).astype(cv.dtype), mode="drop")
+        ck = _write_pages(ck, layer, page, off, k[:, 0])
+        cv = _write_pages(cv, layer, page, off, v[:, 0])
         fn = decode_attn_fn or L.decode_attention_paged
-        o = fn(q[:, 0], new_ck, new_cv, bt, pos, head_dim=hd,
-               quant=paged.get("quant"))[:, None]
+        o = fn(q[:, 0], ck, cv, bt, pos, head_dim=hd,
+               quant=paged.get("quant"), layer=layer)[:, None]
     elif paged is not None:
         # paged prefill: suffix flash vs its own KV merged with a broadcast
         # (never replicated) read of the shared prefix pages; new KV is
-        # committed straight into the rows' pages
+        # committed straight into the rows' pages.  The new tokens sit at
+        # positions at or past prefix_len, so committing them first leaves
+        # every prefix slot the attention reads as it was, and the read
+        # then follows the in-place write instead of forcing a copy
         assert mode == "prefill" and not cfg.sliding_window
         bt = paged["block_tables"]
         pt = paged.get("prefix_table")
         plen = paged.get("prefix_len", jnp.int32(0))
-        KV_, P_, ps_, Dp_ = ck.shape
+        _, KV_, P_, ps_, Dp_ = ck.shape
+        ck = _write_prefill_pages(ck, layer, bt, positions, k)
+        cv = _write_prefill_pages(cv, layer, bt, positions, v)
         if pt is not None and pt.shape[0]:
             safe_pt = jnp.clip(pt, 0, P_ - 1)
-            kp = ck[:, safe_pt]                       # (KV, npre, ps, Dp)
-            vp = cv[:, safe_pt]
+            kp = L.gather_pages(ck, safe_pt, layer)   # (KV, npre, ps, Dp)
+            vp = L.gather_pages(cv, safe_pt, layer)
             if paged.get("quant") is not None:
-                kp, vp = _dequant_pages(paged["quant"], safe_pt, kp, vp)
+                kp, vp = _dequant_pages(paged["quant"], safe_pt, kp, vp,
+                                        layer)
             kp = kp.transpose(1, 2, 0, 3).reshape(-1, KV_, Dp_)[..., :hd]
             vp = vp.transpose(1, 2, 0, 3).reshape(-1, KV_, Dp_)[..., :hd]
         else:
             kp = jnp.zeros((0, KV_, hd), ck.dtype)
             vp = jnp.zeros((0, KV_, hd), cv.dtype)
         o = L.prefix_suffix_attention(q, kp, vp, k, v, positions, plen)
-        page, off = _page_slots(bt, positions, P_, ps_)
-        new_ck = ck.at[:, page, off].set(
-            _fold_write(k, Dp_).astype(ck.dtype), mode="drop")
-        new_cv = cv.at[:, page, off].set(
-            _fold_write(v, Dp_).astype(cv.dtype), mode="drop")
     elif mode == "decode":
-        lc = ck.shape[1]
+        lc = ck.shape[2]
         if row_idx is not None:
             # per-row write slots (continuous batching: ragged fill levels)
             slot_b = row_idx % lc                          # (B,)
-            hit = (jnp.arange(lc)[None, :] == slot_b[:, None])  # (B, lc)
-            new_ck = jnp.where(hit[:, :, None, None], k.astype(ck.dtype), ck)
-            new_cv = jnp.where(hit[:, :, None, None], v.astype(cv.dtype), cv)
+            rows = jnp.arange(B)
+            ck = ck.at[layer, rows, slot_b].set(k[:, 0].astype(ck.dtype),
+                                                unique_indices=True)
+            cv = cv.at[layer, rows, slot_b].set(v[:, 0].astype(cv.dtype),
+                                                unique_indices=True)
+            hit = jnp.arange(lc)[None, :] == slot_b[:, None]   # (B, lc)
             spos = jnp.where(hit, positions[:, :1], slot_pos)
         else:
             slot = idx % lc
-            new_ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, slot, 0, 0))
-            new_cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, slot, 0, 0))
+            ck = _put(ck, layer, k, 0, slot, 0, 0)
+            cv = _put(cv, layer, v, 0, slot, 0, 0)
             spos = jnp.where(jnp.arange(lc)[None, :] == slot, positions[:, :1],
                              slot_pos)
         fn = decode_attn_fn or L.decode_attention
-        o = fn(q[:, 0], new_ck, new_cv, spos, positions[:, 0])[:, None]
+        o = fn(q[:, 0], _layer(ck, layer), _layer(cv, layer), spos,
+               positions[:, 0])[:, None]
     elif mode == "prefill" and extend_offset > 0:
         off = extend_offset
-        lc = ck.shape[1]
+        lc = ck.shape[2]
         assert off + S <= lc and not cfg.sliding_window, (off, S, lc)
-        k_all = jnp.concatenate([ck[:, :off].astype(k.dtype), k], axis=1)
-        v_all = jnp.concatenate([cv[:, :off].astype(v.dtype), v], axis=1)
+        # the write lands past the cached [:off] that attention reads
+        ck = _put(ck, layer, k, 0, off, 0, 0)
+        cv = _put(cv, layer, v, 0, off, 0, 0)
+        k_all = jnp.concatenate(
+            [_layer(ck, layer)[:, :off].astype(k.dtype), k], axis=1)
+        v_all = jnp.concatenate(
+            [_layer(cv, layer)[:, :off].astype(v.dtype), v], axis=1)
         kv_pos = jnp.concatenate([slot_pos[:, :off], positions], axis=1)
         fn = attn_fn or L.flash_attention
         o = fn(q, k_all, v_all, positions, kv_pos,
                causal=cfg.causal, window=0,
                prefix_len=cfg.num_prefix_tokens if cfg.family == VLM else 0)
-        new_ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                              (0, off, 0, 0))
-        new_cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                              (0, off, 0, 0))
     else:
         fn = attn_fn or L.flash_attention
         o = fn(q, k, v, positions, positions,
                causal=cfg.causal, window=cfg.sliding_window,
                prefix_len=cfg.num_prefix_tokens if cfg.family == VLM else 0)
         if mode == "prefill":
-            lc = ck.shape[1]
+            lc = ck.shape[2]
             if S >= lc:
+                # the last lc tokens, each at its ring slot
                 shift = S % lc
-                new_ck = jnp.roll(k[:, S - lc:].astype(ck.dtype), shift, axis=1)
-                new_cv = jnp.roll(v[:, S - lc:].astype(cv.dtype), shift, axis=1)
-            else:
-                new_ck = jax.lax.dynamic_update_slice(
-                    ck, k.astype(ck.dtype), (0, 0, 0, 0))
-                new_cv = jax.lax.dynamic_update_slice(
-                    cv, v.astype(cv.dtype), (0, 0, 0, 0))
+                k = jnp.roll(k[:, S - lc:], shift, axis=1)
+                v = jnp.roll(v[:, S - lc:], shift, axis=1)
+            ck = _put(ck, layer, k, 0, 0, 0, 0)
+            cv = _put(cv, layer, v, 0, 0, 0, 0)
     out = jnp.einsum("bshd,hdm->bsm", o, lp["attn.wo"].astype(cd))
-    return out, new_ck, new_cv
+    return out, ck, cv
 
 
 def _rope_setup(cfg: ModelConfig):
@@ -636,72 +701,67 @@ def _mlp(cfg: ModelConfig, x, lp, valid, dense: bool):
                       lp["mlp.b_out"].astype(x.dtype)), None
 
 
-def _block(cfg: ModelConfig, x, lp, positions, mode, cache_l, *,
+def _block(cfg: ModelConfig, x, lp, positions, mode, cache, layer, *,
            attn_fn=None, decode_attn_fn=None, scan_fn=None,
            extend_offset: int = 0, kv_cs=MOE.Identity, paged=None):
-    """One residual block. cache_l: per-layer cache slice dict (or {}).
-    Returns (x, new cache slice, distinct experts chosen or None)."""
+    """One residual block. cache: the stacked caches of every layer and the
+    step's shared entries (slot_pos, row_idx, idx), or {} in train mode; the
+    block reads its slices at `layer` and writes back, in place, only what
+    it changes.  Returns (x, cache, distinct experts chosen or None)."""
     eps = cfg.eps
-    new_cache = dict(cache_l)
-    slot_pos = cache_l.get("slot_pos")
-    idx = cache_l.get("idx", jnp.int32(0))
-    if paged is not None and "kq" in cache_l:
-        # attach this layer's int8 shadow pool + scales (scanned-in slices)
-        # alongside the shared frozen-page flags
+    cache = dict(cache)
+    slot_pos = cache.get("slot_pos")
+    idx = cache.get("idx", jnp.int32(0))
+    if paged is not None and "kq" in cache:
+        # attach the int8 shadow pools + scales (read at `layer`) alongside
+        # the shared frozen-page flags
         paged = {**paged, "quant": {
-            "kq": cache_l["kq"], "vq": cache_l["vq"],
-            "kscale": cache_l["kscale"], "vscale": cache_l["vscale"],
+            **{k: cache[k] for k in ("kq", "vq", "kscale", "vscale")},
             "flags": paged["quant_flags"]}}
 
-    if cfg.family == HYBRID:
-        xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_attn"), eps)
-        a, nk, nv = _attention(cfg, xin, lp, positions, mode,
-                               cache_l.get("k"), cache_l.get("v"), slot_pos, idx,
-                               attn_fn, decode_attn_fn, extend_offset,
-                               cache_l.get("row_idx"), kv_cs, paged)
+    def attention(xin):
+        a, nk, nv = _attention(cfg, xin, lp, positions, mode, cache.get("k"),
+                               cache.get("v"), layer, slot_pos, idx, attn_fn,
+                               decode_attn_fn, extend_offset,
+                               cache.get("row_idx"), kv_cs, paged)
+        if mode != "train":
+            cache.update(k=nk, v=nv)
+        return a
+
+    def ssm(xin):
         state = None
         if mode != "train":
-            state = M.SSMState(conv=cache_l["conv"], h=cache_l["h"])
+            state = M.SSMState(conv=_layer(cache["conv"], layer),
+                               h=_layer(cache["h"], layer))
         s, new_state = M.mamba_mixer(
             xin, {k[4:]: v for k, v in lp.items() if k.startswith("ssm.")},
             ssm_state_dim=cfg.ssm_state, dt_rank=cfg.dt_rank_eff,
             conv_dim=cfg.ssm_conv, mode=("decode" if mode == "decode" else "train"),
             state=state, scan_fn=scan_fn or M.selective_scan)
-        x = x + 0.5 * (a + s)
         if mode != "train":
-            new_cache.update(k=nk, v=nv, conv=new_state.conv, h=new_state.h)
+            cache.update(conv=_put(cache["conv"], layer, new_state.conv, 0, 0, 0),
+                         h=_put(cache["h"], layer, new_state.h, 0, 0, 0))
+        return s
+
+    if cfg.family == HYBRID:
+        xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_attn"), eps)
+        x = x + 0.5 * (attention(xin) + ssm(xin))
         xin2 = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_mlp"), eps)
         x = x + L.swiglu_mlp(xin2, lp["mlp.w_gate"].astype(x.dtype),
                              lp["mlp.w_up"].astype(x.dtype),
                              lp["mlp.w_down"].astype(x.dtype))
-        return x, new_cache, None
+        return x, cache, None
 
     if cfg.family == SSM:
         xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_ssm"), eps)
-        state = None
-        if mode != "train":
-            state = M.SSMState(conv=cache_l["conv"], h=cache_l["h"])
-        s, new_state = M.mamba_mixer(
-            xin, {k[4:]: v for k, v in lp.items() if k.startswith("ssm.")},
-            ssm_state_dim=cfg.ssm_state, dt_rank=cfg.dt_rank_eff,
-            conv_dim=cfg.ssm_conv, mode=("decode" if mode == "decode" else "train"),
-            state=state, scan_fn=scan_fn or M.selective_scan)
-        if mode != "train":
-            new_cache.update(conv=new_state.conv, h=new_state.h)
-        return x + s, new_cache, None
+        return x + ssm(xin), cache, None
 
     # attention families: dense / moe / encoder / vlm
     xin = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_attn"), eps)
-    a, nk, nv = _attention(cfg, xin, lp, positions, mode,
-                           cache_l.get("k"), cache_l.get("v"), slot_pos, idx,
-                           attn_fn, decode_attn_fn, extend_offset,
-                           cache_l.get("row_idx"), kv_cs, paged)
-    x = x + a
-    if mode != "train" and cfg.has_attention:
-        new_cache.update(k=nk, v=nv)
+    x = x + attention(xin)
     xin2 = L.apply_norm(cfg.norm_type, x, _norm_p(lp, "ln_mlp"), eps)
     y, touched = _mlp(cfg, xin2, lp, positions >= 0, dense=False)
-    return x + y, new_cache, touched
+    return x + y, cache, touched
 
 
 def _mla_stacks(cfg: ModelConfig, params, x, positions, mode, pool, paged):
@@ -749,10 +809,12 @@ def forward(cfg: ModelConfig, params: PyTree, batch: Dict[str, jax.Array],
             remat_policy: str = "nothing"
             ) -> Tuple[jax.Array, Optional[Dict[str, Any]]]:
     """Run the stack. batch: tokens (B,S) int32 | embeds (B,S,M); positions
-    (B,S). Returns (logits (B,S,V), new_cache or None).  For a model with
-    experts, a returned paged cache also holds "expert_loads": per expert
-    layer, the distinct experts the step's tokens (pads excluded) routed
-    to."""
+    (B,S). Returns (logits (B,S,V), new_cache or None).  The stacked cache
+    rides in the layer scan's carry: each layer writes only its new tokens'
+    K/V (or its SSM state) into its slice, so a donated cache is updated in
+    place.  For a model with experts, a returned paged cache also holds
+    "expert_loads": per expert layer, the distinct experts the step's
+    tokens (pads excluded) routed to."""
     cd = _dt(cfg.compute_dtype)
     positions = batch["positions"]
 
@@ -803,23 +865,21 @@ def forward(cfg: ModelConfig, params: PyTree, batch: Dict[str, jax.Array],
         assert not cfg.first_dense_layers, \
             "leading dense layers come with latent attention only"
 
-        def body(x, xs):
-            lp, cl = xs
-            cl = dict(cl)
-            if slot_pos is not None:
-                cl["slot_pos"] = slot_pos
-            if row_idx is not None:
-                cl["row_idx"] = row_idx
-            cl["idx"] = idx
-            y, nc, touched = _block(cfg, x, lp, positions, mode, cl,
+        shared = {k: shared_cache[k] for k in ("slot_pos", "row_idx")
+                  if k in shared_cache}
+        shared["idx"] = idx
+
+        def body(carry, xs):
+            x, lc = carry
+            lp, layer = xs
+            y, nc, touched = _block(cfg, x, lp, positions, mode,
+                                    {**lc, **shared}, layer,
                                     attn_fn=attn_fn,
                                     decode_attn_fn=decode_attn_fn,
                                     scan_fn=scan_fn,
                                     extend_offset=extend_offset, kv_cs=kv_cs,
                                     paged=paged)
-            y = residual_cs(y)
-            nc = {k: nc[k] for k in _LAYER_CACHE_KEYS if k in nc}
-            return y, (nc, touched)
+            return (residual_cs(y), {k: nc[k] for k in lc}), touched
 
         policies = {
             "nothing": jax.checkpoint_policies.nothing_saveable,
@@ -828,8 +888,12 @@ def forward(cfg: ModelConfig, params: PyTree, batch: Dict[str, jax.Array],
         body_fn = jax.checkpoint(body, policy=policies[remat_policy]) \
             if (remat and mode == "train") else body
 
-        x, (new_layer_cache, touched) = jax.lax.scan(
-            body_fn, x, (stacked, layer_cache), unroll=unroll_layers)
+        # the stacked cache rides in the carry: each layer writes its new
+        # K/V (or state) in place, and no stacked output is rebuilt
+        n = jax.tree.leaves(stacked)[0].shape[0]
+        (x, new_layer_cache), touched = jax.lax.scan(
+            body_fn, (x, layer_cache),
+            (stacked, jnp.arange(n, dtype=jnp.int32)), unroll=unroll_layers)
 
     fn_params = {k: v for k, v in params.items() if k.startswith("final_norm")}
     x = L.apply_norm(cfg.norm_type, x, _norm_p(fn_params, "final_norm"),

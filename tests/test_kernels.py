@@ -1,4 +1,6 @@
 """Per-kernel allclose sweeps (interpret=True) against the ref.py oracles."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -175,6 +177,43 @@ def test_decode_attention_paged_quant(B, H, KV, D, ps, NB, P):
     rf = rf.reshape(B, KV, G, D).reshape(B, H, D)
     np.testing.assert_allclose(np.asarray(out), np.asarray(rf),
                                atol=0.08, rtol=0.08)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_decode_attention_paged_reads_a_stacked_pool(quantized):
+    """Pools stacked over layers and read at `layer` give what the layer's
+    own pool gives, in the kernel (a free view of the whole stack, offset
+    by the layer's pages) and in its jnp twin (one gather from the
+    stack)."""
+    from repro.models import layers as LY
+    B, H, KV, D, ps, NB, P, NL = 2, 4, 2, 32, 16, 4, 12, 3
+    ks = _split(3)
+    q = jax.random.normal(ks[0], (B, H, D))
+    kp = jnp.stack([_folded_pools(k, KV, P, ps, D, jnp.float32)[0]
+                    for k in jax.random.split(ks[1], NL)])
+    vp = jnp.stack([_folded_pools(k, KV, P, ps, D, jnp.float32)[0]
+                    for k in jax.random.split(ks[2], NL)])
+    bt, qpos, _, _, _ = _ragged_tables(B, KV, P, ps, NB, 11)
+    quant = None
+    if quantized:
+        amax = jnp.max(jnp.abs(kp), axis=(3, 4))            # (NL, KV, P)
+        kscale = jnp.maximum(amax, 1e-8) / 127.0
+        vscale = jnp.maximum(jnp.max(jnp.abs(vp), axis=(3, 4)), 1e-8) / 127.0
+        quant = {"kq": jnp.round(kp / kscale[..., None, None]).astype(jnp.int8),
+                 "vq": jnp.round(vp / vscale[..., None, None]).astype(jnp.int8),
+                 "kscale": kscale, "vscale": vscale,
+                 "flags": jnp.asarray([p % 2 for p in range(P)], jnp.int32)}
+    for layer in range(NL):
+        one = None if quant is None else \
+            {k: (x if k == "flags" else x[layer]) for k, x in quant.items()}
+        for fn in (functools.partial(ops.decode_attention_paged,
+                                     interpret=True),
+                   LY.decode_attention_paged):
+            want = fn(q, kp[layer], vp[layer], bt, qpos, head_dim=D,
+                      quant=one)
+            got = fn(q, kp, vp, bt, qpos, head_dim=D, quant=quant,
+                     layer=jnp.int32(layer))
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_decode_attention_paged_shared_prefix_pages():

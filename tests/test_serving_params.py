@@ -144,23 +144,70 @@ def test_dense_steps_match_the_float32_tree(arch):
         tok, dpos = jnp.argmax(lg_f, -1)[:, None].astype(jnp.int32), dpos + 1
 
 
-@pytest.mark.parametrize("arch", ARCHS)
+def _layer_by_layer_decode(cfg, params, tok, pos, cache, bt):
+    """The paged decode step as a loop over layers, each handed its own
+    slice of the pool and returning its new slice, stacked back after the
+    last layer: the formulation the scan had before the pool rode in its
+    carry."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    x = jnp.take(params["embed"].astype(cd), tok, axis=0)
+    keys = [k for k in ("k", "v", "conv", "h") if k in cache]
+    new = {k: [] for k in keys}
+    for i in range(cfg.num_layers):
+        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        cl = {k: cache[k][i:i + 1] for k in keys}
+        x, cl, _ = MDL._block(cfg, x, lp, pos, "decode",
+                              {**cl, "idx": cache["idx"]}, 0,
+                              paged={"block_tables": bt})
+        for k in keys:
+            new[k].append(cl[k][0])
+    x = MDL.L.apply_norm(cfg.norm_type, x, MDL._norm_p(params, "final_norm"),
+                         cfg.eps)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return (x @ head.astype(cd))[:, 0], {k: jnp.stack(v)
+                                         for k, v in new.items()}
+
+
+# the served architecture again, with two of three rows' writes dropped: one
+# row's table entry for its position is -1, another's position lies past its
+# table
+@pytest.mark.parametrize("arch", ARCHS + ["olmo-1b-dropped"])
 def test_paged_decode_matches_the_float32_tree(arch):
-    cfg = C.get_smoke_config(arch)
+    dropped = arch.endswith("-dropped")
+    cfg = C.get_smoke_config(arch.removesuffix("-dropped"))
+    if dropped:
+        # float32 compute: the loop below then gives the step's logits to
+        # the last bit, with no bf16 rounding that differs between programs
+        cfg = cfg.replace(compute_dtype="float32")
     params = _params(cfg)
     eng = InferenceEngine(cfg, params=params, max_len=64, kv_layout="paged",
                           page_size=16)
     B, P, PS, NB = 2, 8, 16, 4
+    if dropped:
+        B, P = 3, 12
     cache = MDL.init_paged_cache(cfg, P, PS, B)
     keys = iter(jax.random.split(jax.random.PRNGKey(2), len(cache)))
     cache = {k: (v if k == "idx" else
                  jax.random.normal(next(keys), v.shape).astype(v.dtype))
              for k, v in cache.items()}
     bt = jnp.arange(B * NB, dtype=jnp.int32).reshape(B, NB)
-    tok = jnp.array([[5], [9]], jnp.int32)
-    pos = jnp.array([[20], [37]], jnp.int32)
+    tok = jnp.array([[5], [9], [2]][:B], jnp.int32)
+    pos = jnp.array([[20], [37], [70]][:B], jnp.int32)
+    if dropped:
+        bt = bt.at[1, 37 // PS].set(-1)
     decode = eng._decode_fn_paged(NB)
     lg_s, c_s = decode(eng.params, tok, pos, _copy(cache), bt, None)
     lg_f, c_f = decode(params, tok, pos, _copy(cache), bt, None)
     _same(lg_s, lg_f)
     _same(c_s, c_f)
+    if dropped:
+        want, pools = jax.jit(_layer_by_layer_decode, static_argnums=0)(
+            cfg, params, tok, pos, cache, bt)
+        _same(lg_f, want)
+        _same({k: c_f[k] for k in pools}, pools)
+        # row 0 wrote its slot; the other rows wrote nothing anywhere
+        page, off = int(bt[0, 20 // PS]), 20 % PS
+        for k in ("k", "v"):
+            moved = c_f[k] != cache[k]
+            assert bool(moved[:, :, page, off].any())
+            assert not bool(moved.at[:, :, page, off].set(False).any())

@@ -145,6 +145,10 @@ def test_full_width_decode_step_fits_one_chip(one_chip, layout):
     # bf16 weights are ~2.4 GB of the arguments, the KV cache ~2.1 GB more;
     # the whole step must fit the chip
     assert 4e9 < used < HBM_BYTES, used
+    # the stacked cache (pool) is written in place: no second copy of it
+    # is built as a temporary
+    kv = MDL.tree_bytes({k: cache[k] for k in ("k", "v")})
+    assert mem.temp_size_in_bytes < kv, (mem.temp_size_in_bytes, kv)
 
 
 def test_full_width_prefill_step_takes_bf16_weights(one_chip):
